@@ -713,6 +713,8 @@ ONLY = {"simloop": bench_simloop, "faults": bench_faults,
 
 def main(smoke: bool = False, json_path: str = None,
          only: str = None) -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     if only:
         ONLY[only](smoke=smoke)

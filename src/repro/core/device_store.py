@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.objectives import F32
+
 
 def _pow2(n: int) -> int:
     p = 1
@@ -108,7 +110,8 @@ def _flush(preds, pnorm, masks, acc, S, labels, nv, rows, row_mask,
     # XLA:CPU transpose-copy the whole resident tensor first
     rg = rn.reshape(K, R, -1)
     srows = (jnp.einsum("krx,kmx->krm", rg,
-                        block.reshape(block.shape[0], block.shape[1], -1))
+                        block.reshape(block.shape[0], block.shape[1], -1),
+                        precision=F32)
              / nv[cu][:, None, None])
     S = S.at[cu[:, None], slots].set(srows)      # dirty rows ...
     S = S.at[cu[:, None], :, slots].set(srows)   # ... + symmetric columns

@@ -7,15 +7,16 @@ with a `while_loop`, and applies tournament selection / uniform crossover
 / bit-flip mutation / exact-k repair as vectorized bit ops. On TPU this
 turns the paper's per-client CPU hot loop into an MXU-shaped batch job.
 
-Two entry points share the same genetic step (DESIGN.md §3):
+Two entry points, one program (DESIGN.md §3):
 
-  run_nsga2          — one client's GA, explicit `key` (falls back to
-                       `cfg.seed` for backwards compatibility).
   run_nsga2_batched  — N clients at once: the per-generation genetic ops
                        are `jax.vmap`-ed over the client axis while the
                        objective evaluation sees the whole (N, P, M)
                        population in one call (so a batched Pallas kernel
                        can score every client's population in one launch).
+  run_nsga2          — one client's GA: the batched run at N=1, explicit
+                       `key` (falls back to `cfg.seed` for backwards
+                       compatibility).
 
 Each client gets its OWN PRNG stream (`keys[(N, 2)]`); clients no longer
 share one GA random sequence through `NSGAConfig.seed`.
@@ -155,8 +156,10 @@ def _survival_order(aobjs):
 
 def run_nsga2(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
               key=None, init_pop=None, valid_mask=None):
-    """eval_fn: (P, M) 0/1 float -> (P, n_obj) objectives (maximized).
+    """One client's GA: `run_nsga2_batched` at N=1, so a serial run and
+    client i of a batch execute the same program.
 
+    eval_fn: (P, M) 0/1 float -> (P, n_obj) objectives (maximized).
     `key` is this run's PRNG stream (defaults to PRNGKey(cfg.seed) for
     backwards compatibility). `valid_mask` (M,) 0/1 freezes masked slots
     at zero (padding models that have not arrived yet — DESIGN.md §4).
@@ -164,44 +167,28 @@ def run_nsga2(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
     Returns dict(pop, objs, ranks) of the final population. Entirely
     jittable; the caller closes eval_fn over acc/S (objectives.py).
     """
-    P, M, k = cfg.pop_size, n_models, cfg.k
     if key is None:
         key = jax.random.PRNGKey(cfg.seed)
-    key, k0, k1 = jax.random.split(key, 3)
-    pop = _init_population(k0, k1, P, M, k, valid_mask, init_pop)
-
-    def gen(pop, key_g):
-        objs = eval_fn(pop)
-        ranks = nondominated_rank(objs)
-        crowd = crowding_distance(objs, ranks)
-        child = _breed(pop, ranks, crowd, key_g, cfg, valid_mask)
-        # elitist (mu + lambda) survival over combined 2P pool
-        allp = jnp.concatenate([pop, child], axis=0)
-        aobjs = eval_fn(allp)
-        order, _, _ = _survival_order(aobjs)
-        pop = allp[order[:P]]
-        return pop, None
-
-    keys = jax.random.split(key, cfg.generations)
-    pop, _ = jax.lax.scan(gen, pop, keys)
-    objs = eval_fn(pop)
-    ranks = nondominated_rank(objs)
-    return {"pop": pop, "objs": objs, "ranks": ranks}
+    out = run_nsga2_batched(
+        lambda pop: eval_fn(pop[0])[None], n_models, cfg, key[None],
+        init_pop=init_pop,
+        valid_mask=None if valid_mask is None else valid_mask[None])
+    return {k: v[0] for k, v in out.items()}
 
 
 def run_nsga2_batched(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
                       keys, init_pop=None, valid_mask=None):
     """N clients' GAs in lockstep. eval_fn: (N, P, M) -> (N, P, n_obj).
 
-    `keys`: (N, 2) uint32 — one independent PRNG stream per client, split
-    exactly like the serial path so client i's run is bit-identical to
-    `run_nsga2(..., key=keys[i])` up to the batched eval's reduction
-    order. `valid_mask`: optional (N, M) 0/1 per-client model-slot mask.
+    `keys`: (N, 2) uint32 — one independent PRNG stream per client.
+    `valid_mask`: optional (N, M) 0/1 per-client model-slot mask.
 
     The genetic operators are vmapped over the client axis; the two
     objective evaluations per generation see the full (N, P|2P, M)
     population, which is what lets a batched Pallas kernel score every
-    client in a single launch (kernels/ensemble_fitness).
+    client in a single launch (kernels/ensemble_fitness). Each
+    generation: evaluate, rank, breed, then elitist (mu + lambda)
+    survival over the combined 2P pool.
     """
     P, M, k = cfg.pop_size, n_models, cfg.k
     sub = jax.vmap(lambda kk: jax.random.split(kk, 3))(keys)  # (N, 3, 2)

@@ -16,7 +16,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .nsga2 import NSGAConfig, client_keys, run_nsga2, run_nsga2_batched
+from .nsga2 import NSGAConfig, client_keys, run_nsga2_batched
 from .objectives import (ensemble_accuracy, member_accuracy,
                          population_objectives, similarity_matrix)
 
@@ -37,38 +37,28 @@ def _pick_winner(pop, objs, ranks, probs_val, labels_val, acc):
     }
 
 
-@partial(jax.jit, static_argnames=("nsga", "use_kernel"))
 def select_ensemble(probs_val, labels_val, nsga: NSGAConfig,
                     use_kernel: bool = False, key=None, model_mask=None):
-    """probs_val: (M, V, C) bench predictions on the local validation set.
+    """One client's selection: `select_ensembles` at N=1, so it runs the
+    same program as client i of a batch.
 
+    probs_val: (M, V, C) bench predictions on the local validation set.
     `key` — this client's PRNG stream (defaults to PRNGKey(nsga.seed));
     `model_mask` — optional (M,) 0/1 valid-slot mask (padding slots whose
     predictions have not arrived are never selected).
 
     Returns dict with:
       chromosome (M,) 0/1 — the selected ensemble,
-      pareto_pop/pareto_objs — the final Pareto front (Fig. 3),
+      pop/objs/pareto_mask — the final population and its front (Fig. 3),
       val_accuracy — overall validation accuracy of the winner.
     """
-    M = probs_val.shape[0]
-    acc = member_accuracy(probs_val, labels_val)
-    S = similarity_matrix(probs_val, labels_val)
-
-    if use_kernel:
-        from repro.kernels.ensemble_fitness import ops as ef_ops
-
-        def eval_fn(pop):
-            st, dv = ef_ops.ensemble_fitness(pop, acc, S)
-            return jnp.stack([st, dv], axis=1)
-    else:
-        def eval_fn(pop):
-            st, dv = population_objectives(pop, acc, S)
-            return jnp.stack([st, dv], axis=1)
-
-    out = run_nsga2(eval_fn, M, nsga, key=key, valid_mask=model_mask)
-    return _pick_winner(out["pop"], out["objs"], out["ranks"],
-                        probs_val, labels_val, acc)
+    if key is None:
+        key = jax.random.PRNGKey(nsga.seed)
+    out = select_ensembles(
+        probs_val[None], labels_val[None], nsga, use_kernel=use_kernel,
+        keys=key[None],
+        model_mask=None if model_mask is None else model_mask[None])
+    return {k: v[0] for k, v in out.items()}
 
 
 @jax.jit

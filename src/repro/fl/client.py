@@ -69,17 +69,20 @@ def predict_probs(family: str, cfg: CNNConfig, params, x: np.ndarray,
 def _multi_predict_fn(family: str, cfg: CNNConfig):
     @jax.jit
     def predict_chunk_multi(stacked_params, xb):
-        # stacked_params: every leaf gains a leading model axis
-        return jax.vmap(
-            lambda p: jax.nn.softmax(apply_model(family, p, xb), axis=-1)
-        )(stacked_params)
+        # stacked_params: every leaf gains a leading model axis. A loop
+        # over models, not a vmap: vmapped convolutions become grouped
+        # convolutions, and the TPU compiler (libtpu 0.0.34) overflows
+        # its stack emitting cnn4's at 5 or more stacked models.
+        return jax.lax.map(
+            lambda p: jax.nn.softmax(apply_model(family, p, xb), axis=-1),
+            stacked_params)
     return predict_chunk_multi
 
 
 def predict_probs_batched(family: str, cfg: CNNConfig, params_seq,
                           x: np.ndarray) -> np.ndarray:
     """Batched multi-model inference: evaluate ALL of one family's models
-    on `x` in one vmapped jitted call per chunk -> (n_models, N, C).
+    on `x` in one jitted call per chunk -> (n_models, N, C).
 
     This is the exchange-layer hot path: building a client's prediction
     store evaluates n_owners models per family, and stacking their

@@ -1,24 +1,29 @@
 """Pallas TPU kernel: score a whole NSGA-II population.
 
 The paper evaluates P x G candidate ensembles per client sequentially on
-CPU; on TPU the population is scored as blocked matmuls. Grid tiles the
-population (rows); each step keeps a (BLOCK_P, M) chromosome tile, the
-(M,) accuracy vector and the (M, M) similarity Gram matrix resident in
-VMEM (M <= ~1500 comfortably fits: M^2 fp32 @ M=1024 is 4 MB).
+CPU; on TPU the population is scored as blocked matmuls. Grid step
+(n, i) keeps client n's i-th (BLOCK_P, M) chromosome tile, its (1, M)
+accuracy and diag(S) rows and its (M, M) similarity Gram matrix resident
+in VMEM (M <= ~1500 comfortably fits: M^2 fp32 @ M=1024 is 4 MB).
 
   strength  = (C @ acc) / k
   diversity = 1 - (rowsum((C @ S) * C) - C @ diag(S)) / (k (k-1))
 
+The math is `core.objectives.fitness_terms`, the same function the jnp
+path runs, so both paths score a chromosome identically. It yields
+(BLOCK_P, 1) columns; the kernel packs them into lanes 0 and 1 of one
+(BLOCK_P, 128) tile and transposes it, so the output block is a
+lane-dense (1, 2, BLOCK_P) slab of an (N, 2, Pp) array. The last two
+dimensions of every block equal the array's or divide by (8, 128), as
+Mosaic requires for any client count N.
+
 Two entry points:
 
-  ensemble_fitness          — one client: pop (P, M), acc (M,), S (M, M).
-  ensemble_fitness_batched  — N clients in ONE launch: the client axis is
-                              folded into the grid as a leading dimension
-                              (grid = (N, P // BLOCK_P)), so grid step
-                              (n, i) scores client n's i-th population
-                              tile against client n's own acc/S blocks.
-                              This is what `select_ensembles`'s vmapped
-                              NSGA-II calls with use_kernel=True.
+  ensemble_fitness_batched  — N clients in ONE launch, grid
+                              (N, Pp // BLOCK_P). This is what
+                              `select_ensembles`'s vmapped NSGA-II calls
+                              with use_kernel=True.
+  ensemble_fitness          — one client: the batched kernel at N=1.
 """
 from __future__ import annotations
 
@@ -28,68 +33,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.objectives import fitness_terms
+
 BLOCK_P = 128
+LANES = 128
 
 
-def _fitness_math(c, acc, S, diag):
-    """c: (BLOCK_P, M); acc: (1, M); S: (M, M); diag: (1, M) = diag(S),
-    precomputed by the host wrapper -> (strength, diversity). Passing the
-    diagonal in keeps the kernel from materializing an (M, M) iota mask
-    in VMEM every grid step just to re-extract it."""
-    k = jnp.sum(c, axis=1)
-    kc = jnp.maximum(k, 1.0)
-    strength = (c @ acc[0][:, None])[:, 0] / kc  # MXU matvec
-    cs = jax.lax.dot(c, S, preferred_element_type=jnp.float32)  # (BLOCK_P, M)
-    quad = jnp.sum(cs * c, axis=1)
-    self_sim = (c @ diag[0][:, None])[:, 0]
-    pairs = jnp.maximum(k * (k - 1.0), 1.0)
-    return strength, 1.0 - (quad - self_sim) / pairs
-
-
-def _kernel(pop_ref, acc_ref, S_ref, diag_ref, strength_ref, diversity_ref):
-    strength, diversity = _fitness_math(pop_ref[...], acc_ref[...],
-                                        S_ref[...], diag_ref[...])
-    strength_ref[...] = strength
-    diversity_ref[...] = diversity
-
-
-def _kernel_batched(pop_ref, acc_ref, S_ref, diag_ref, strength_ref,
-                    diversity_ref):
+def _kernel(pop_ref, acc_ref, S_ref, diag_ref, out_ref):
     # blocks carry a leading singleton client dim: (1, BLOCK_P, M) etc.
-    strength, diversity = _fitness_math(pop_ref[0], acc_ref[0], S_ref[0],
+    strength, diversity = fitness_terms(pop_ref[0], acc_ref[0], S_ref[0],
                                         diag_ref[0])
-    strength_ref[0] = strength
-    diversity_ref[0] = diversity
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ensemble_fitness(pop, acc, S, interpret: bool = True):
-    """pop: (P, M) f32; acc: (M,); S: (M, M) -> (strength, diversity)."""
-    P, M = pop.shape
-    pad = (-P) % BLOCK_P
-    if pad:
-        pop = jnp.pad(pop, ((0, pad), (0, 0)))
-    Pp = pop.shape[0]
-    grid = (Pp // BLOCK_P,)
-    out_shape = (jax.ShapeDtypeStruct((Pp,), jnp.float32),
-                 jax.ShapeDtypeStruct((Pp,), jnp.float32))
-    Sf = S.astype(jnp.float32)
-    strength, diversity = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLOCK_P, M), lambda i: (i, 0)),
-            pl.BlockSpec((1, M), lambda i: (0, 0)),
-            pl.BlockSpec((M, M), lambda i: (0, 0)),
-            pl.BlockSpec((1, M), lambda i: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((BLOCK_P,), lambda i: (i,)),
-                   pl.BlockSpec((BLOCK_P,), lambda i: (i,))),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(pop.astype(jnp.float32), acc.astype(jnp.float32)[None, :],
-      Sf, jnp.diagonal(Sf)[None, :])
-    return strength[:P], diversity[:P]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_P, LANES), 1)
+    packed = jnp.where(lane == 0, strength,
+                       jnp.where(lane == 1, diversity, 0.0))
+    out_ref[0] = packed.T[:2]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -101,24 +58,28 @@ def ensemble_fitness_batched(pop, acc, S, interpret: bool = True):
     if pad:
         pop = jnp.pad(pop, ((0, 0), (0, pad), (0, 0)))
     Pp = pop.shape[1]
-    grid = (N, Pp // BLOCK_P)
-    out_shape = (jax.ShapeDtypeStruct((N, Pp), jnp.float32),
-                 jax.ShapeDtypeStruct((N, Pp), jnp.float32))
     Sf = S.astype(jnp.float32)
     diag = jnp.diagonal(Sf, axis1=1, axis2=2)  # (N, M), host-side precompute
-    strength, diversity = pl.pallas_call(
-        _kernel_batched,
-        grid=grid,
+    out = pl.pallas_call(
+        _kernel,
+        grid=(N, Pp // BLOCK_P),
         in_specs=[
             pl.BlockSpec((1, BLOCK_P, M), lambda n, i: (n, i, 0)),
             pl.BlockSpec((1, 1, M), lambda n, i: (n, 0, 0)),
             pl.BlockSpec((1, M, M), lambda n, i: (n, 0, 0)),
             pl.BlockSpec((1, 1, M), lambda n, i: (n, 0, 0)),
         ],
-        out_specs=(pl.BlockSpec((1, BLOCK_P), lambda n, i: (n, i)),
-                   pl.BlockSpec((1, BLOCK_P), lambda n, i: (n, i))),
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((1, 2, BLOCK_P), lambda n, i: (n, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((N, 2, Pp), jnp.float32),
         interpret=interpret,
     )(pop.astype(jnp.float32), acc.astype(jnp.float32)[:, None, :],
       Sf, diag[:, None, :])
-    return strength[:, :P], diversity[:, :P]
+    return out[:, 0, :P], out[:, 1, :P]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ensemble_fitness(pop, acc, S, interpret: bool = True):
+    """pop: (P, M) f32; acc: (M,); S: (M, M) -> (strength, diversity)."""
+    strength, diversity = ensemble_fitness_batched(
+        pop[None], acc[None], S[None], interpret=interpret)
+    return strength[0], diversity[0]

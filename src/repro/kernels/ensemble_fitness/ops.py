@@ -1,26 +1,15 @@
-"""Public jit'd wrappers for the ensemble_fitness kernels. On a CPU host
-the kernels run in interpret mode; on TPU interpret=False.
-
-`ensemble_fitness` dispatches on rank: a (P, M) population uses the
-single-client kernel, an (N, P, M) population the batched kernel (the
-client axis is folded into the Pallas grid, one launch for all clients).
+"""Public wrapper for the batched ensemble_fitness kernel: compiled by
+Mosaic on a TPU, run by the Pallas interpreter on any other backend.
 """
 from __future__ import annotations
 
 import jax
 
-from .kernel import ensemble_fitness as _kernel_call
 from .kernel import ensemble_fitness_batched as _kernel_call_batched
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def ensemble_fitness(pop, acc, S):
-    if pop.ndim == 3:
-        return _kernel_call_batched(pop, acc, S, interpret=_interpret())
-    return _kernel_call(pop, acc, S, interpret=_interpret())
 
 
 def ensemble_fitness_batched(pop, acc, S):

@@ -16,6 +16,7 @@ import argparse
 import json
 import sys
 
+from repro.compile_cache import use_compile_cache
 from repro.sim import Experiment, ExperimentSpec
 
 
@@ -44,6 +45,7 @@ def apply_override(d: dict, path: str, value) -> None:
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.sim.run",
         description="run one JSON-serialized ExperimentSpec end-to-end")

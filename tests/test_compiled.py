@@ -15,7 +15,8 @@ these tolerances were set:
 """
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.sim.experiment import Experiment
 from repro.sim.spec import ExperimentSpec

@@ -409,8 +409,11 @@ def test_compiled_backend_rejects_faults_loudly():
 
 # --------------------------------------------------------- CLI (sat 2)
 
-def test_cli_exits_2_with_one_line_error(tmp_path, capsys):
+def test_cli_exits_2_with_one_line_error(tmp_path, capsys, monkeypatch):
     from repro.sim.run import main as cli
+    # an explicit cache directory keeps the CLI's compile-cache helper
+    # from pointing this test process at the checkout's cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     bad_json = tmp_path / "bad.json"
     bad_json.write_text("{not json")
     assert cli(["--spec", str(bad_json)]) == 2

@@ -26,8 +26,9 @@ with mesh:
     swapped = jax.jit(lambda b: pod_ring_exchange(b, mesh),
                       out_shardings=shard)(bench)
 for a, b in zip(jax.tree.leaves(bench), jax.tree.leaves(swapped)):
-    np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b[1]), atol=0)
-    np.testing.assert_allclose(np.asarray(a[1]), np.asarray(b[0]), atol=0)
+    a, b = np.asarray(a), np.asarray(b)
+    np.testing.assert_allclose(a[0], b[1], atol=0)
+    np.testing.assert_allclose(a[1], b[0], atol=0)
 
 # --- ensemble serve: psum vote == host mean-prob vote
 toks = jax.random.randint(key, (2, 16), 0, cfg.vocab)

@@ -2,7 +2,8 @@
 connectivity, async gossip convergence, baseline smoke runs."""
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.data import dirichlet_partition, make_synthetic_images, split_train_val_test
 from repro.data.partition import partition_stats

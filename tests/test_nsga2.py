@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.nsga2 import (NSGAConfig, crowding_distance, dominance,
                               nondominated_rank, repair_k, run_nsga2)
