@@ -1,0 +1,3 @@
+"""`flush_upload_mb`, read the same way in the synchronous round cells, whose
+batches are a different end-to-end metric's (`round_reselect_ms`)."""
+from chip.metrics.flush_upload_mb import read  # noqa: F401

@@ -1,0 +1,3 @@
+"""`select_host_ms`, read the same way in the synchronous round cells, whose
+batches are a different end-to-end metric's (`round_reselect_ms`)."""
+from chip.metrics.select_host_ms import read  # noqa: F401

@@ -4,8 +4,8 @@ PR 7's contract is that DESIGN.md §11 documents the FULL metric
 namespace, and the parity tier can diff whole frames because names are
 stable. This project rule machine-checks the doc half: it extracts every
 metric-name literal the instrumented code emits (the first string
-argument of ``.inc`` / ``.set`` / ``.observe`` / ``.stopwatch`` calls in
-any scanned file, plus dotted-name string literals inside
+argument of ``.inc`` / ``.set`` / ``.observe`` / ``.stopwatch`` /
+``.span`` calls in any scanned file, plus dotted-name string literals inside
 ``obs/probes.py``'s name/value tuple tables) and cross-checks the set
 against the §11 namespace table in DESIGN.md — failing in BOTH
 directions: an emitted name missing from the table, and a documented
@@ -27,7 +27,7 @@ from repro.analysis.registry import Rule, rule
 
 # a metric name: at least two dotted lowercase segments
 METRIC_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
-_EMIT_METHODS = {"inc", "set", "observe", "stopwatch"}
+_EMIT_METHODS = {"inc", "set", "observe", "stopwatch", "span"}
 # string literals that look dotted but are file names, not metrics
 _NOT_METRICS_SUFFIXES = (".json", ".csv", ".png", ".py", ".md")
 
@@ -41,7 +41,7 @@ def is_metric_name(s: str) -> bool:
 
 def emitted_metrics(ctx) -> Dict[str, int]:
     """name -> first emission line for one FileContext. Emission sites
-    are `<recv>.inc("name", ...)` (and set/observe/stopwatch); in
+    are `<recv>.inc("name", ...)` (and set/observe/stopwatch/span); in
     obs/probes.py, `("name", value)` tuple tables count too — the
     CompiledProbe loops over those before calling inc."""
     out: Dict[str, int] = {}

@@ -39,6 +39,15 @@ slots — scatter and recompute are idempotent), so an async run compiles
 O(log N · log M) flush variants, mirroring the engine's client-batch
 padding. When every client is dirty the per-group block gather is elided
 and the matmul reads the resident normalized tensor directly.
+
+Observability (DESIGN.md §11): `n_bytes_uploaded` counts the host→device
+bytes of every flush (the padded row block and its index arrays), and
+`n_bytes_useful` the part of them that carries real validation samples
+of distinct dirty slots. With an enabled `metrics` registry the flush
+opens host spans (`store.flush` and its drain/assemble/upload/launch
+children) and mirrors both byte counts; the jitted programs carry device
+scopes (`flush`, `flush/scatter`, `flush/gram`, `gather`) in their op
+names.
 """
 from __future__ import annotations
 
@@ -50,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.objectives import F32
+from repro.obs.metrics import NULL_METRICS
 
 
 def _pow2(n: int) -> int:
@@ -92,29 +102,32 @@ def _flush(preds, pnorm, masks, acc, S, labels, nv, rows, row_mask,
     the full rebuild's normalize-everything + `O(N · M² · V · C)` Gram.
     """
     K, R = slots.shape
-    ci = jnp.repeat(cu, R)                       # (K·R,) flat client ids
-    si = slots.reshape(-1)                       # (K·R,) flat slot ids
-    lab = labels[ci]                             # (K·R, V)
-    valid = (lab >= 0)
-    rn = rows / (jnp.linalg.norm(rows, axis=-1, keepdims=True) + 1e-12)
-    rn = rn * valid[:, :, None].astype(jnp.float32)
-    preds = preds.at[ci, si].set(rows)
-    pnorm = pnorm.at[ci, si].set(rn)
-    masks = masks.at[ci, si].set(row_mask)
-    hit = (jnp.argmax(rows, axis=-1) == lab) & valid
-    acc = acc.at[ci, si].set(
-        jnp.sum(hit.astype(jnp.float32), axis=-1) / nv[ci])
-    block = pnorm if all_clients else pnorm[cu]  # (K, M, V, C)
-    # contract over the FLATTENED (V·C) axis: a free reshape of the
-    # contiguous trailing dims — the two-axis (v, c) contraction makes
-    # XLA:CPU transpose-copy the whole resident tensor first
-    rg = rn.reshape(K, R, -1)
-    srows = (jnp.einsum("krx,kmx->krm", rg,
-                        block.reshape(block.shape[0], block.shape[1], -1),
-                        precision=F32)
-             / nv[cu][:, None, None])
-    S = S.at[cu[:, None], slots].set(srows)      # dirty rows ...
-    S = S.at[cu[:, None], :, slots].set(srows)   # ... + symmetric columns
+    with jax.named_scope("flush"), jax.named_scope("scatter"):
+        ci = jnp.repeat(cu, R)                   # (K·R,) flat client ids
+        si = slots.reshape(-1)                   # (K·R,) flat slot ids
+        lab = labels[ci]                         # (K·R, V)
+        valid = (lab >= 0)
+        rn = rows / (jnp.linalg.norm(rows, axis=-1, keepdims=True) + 1e-12)
+        rn = rn * valid[:, :, None].astype(jnp.float32)
+        preds = preds.at[ci, si].set(rows)
+        pnorm = pnorm.at[ci, si].set(rn)
+        masks = masks.at[ci, si].set(row_mask)
+        hit = (jnp.argmax(rows, axis=-1) == lab) & valid
+        acc = acc.at[ci, si].set(
+            jnp.sum(hit.astype(jnp.float32), axis=-1) / nv[ci])
+    with jax.named_scope("flush"), jax.named_scope("gram"):
+        block = pnorm if all_clients else pnorm[cu]  # (K, M, V, C)
+        # contract over the FLATTENED (V·C) axis: a free reshape of the
+        # contiguous trailing dims — the two-axis (v, c) contraction
+        # makes XLA:CPU transpose-copy the whole resident tensor first
+        rg = rn.reshape(K, R, -1)
+        srows = (jnp.einsum("krx,kmx->krm", rg,
+                            block.reshape(block.shape[0], block.shape[1],
+                                          -1),
+                            precision=F32)
+                 / nv[cu][:, None, None])
+        S = S.at[cu[:, None], slots].set(srows)      # dirty rows ...
+        S = S.at[cu[:, None], :, slots].set(srows)   # ... + symmetric columns
     return preds, pnorm, masks, acc, S
 
 
@@ -122,7 +135,8 @@ def _flush(preds, pnorm, masks, acc, S, labels, nv, rows, row_mask,
 def _gather(preds, labels, masks, acc, S, idx):
     """Power-of-two client-batch gather, entirely on device."""
     take = lambda a: jnp.take(a, idx, axis=0)  # noqa: E731
-    return take(preds), take(labels), take(masks), take(acc), take(S)
+    with jax.named_scope("gather"):
+        return take(preds), take(labels), take(masks), take(acc), take(S)
 
 
 class DeviceStoreBatch:
@@ -140,6 +154,9 @@ class DeviceStoreBatch:
         self._cursor: List[int] = []       # per-client dirty-log position
         self.n_flushes = 0
         self.n_rows_scattered = 0          # perf counters (bench/DESIGN §7)
+        self.n_bytes_uploaded = 0          # host->device bytes of flushes
+        self.n_bytes_useful = 0            # ... that are real dirty rows
+        self.metrics = NULL_METRICS        # the engine's registry (§11)
         labels = np.full((len(stores), self.v_max), -1, np.int32)
         self.preds = jnp.zeros((len(stores), cap, self.v_max, C), jnp.float32)
         self.pnorm = jnp.zeros_like(self.preds)  # cached normalized mirror
@@ -148,8 +165,10 @@ class DeviceStoreBatch:
         for i, s in enumerate(stores):
             self._attach(s, labels[i])
         self.labels = jnp.asarray(labels)
-        # fp32 valid-sample counts, the shared denominator of acc and S
-        self.nv = jnp.asarray(np.maximum((labels >= 0).sum(1), 1)
+        # valid samples per client on the host (the useful-bytes count),
+        # and in fp32 on device, the shared denominator of acc and S
+        self._n_valid = (labels >= 0).sum(1)
+        self.nv = jnp.asarray(np.maximum(self._n_valid, 1)
                               .astype(np.float32))
         acc0 = np.stack([np.full((cap,), _zero_row_acc(labels[i]), np.float32)
                          for i in range(len(stores))])
@@ -182,6 +201,7 @@ class DeviceStoreBatch:
         row = np.full((1, self.v_max), -1, np.int32)
         self._attach(store, row[0])
         self.labels = jnp.asarray(np.concatenate([labels, row]))
+        self._n_valid = np.concatenate([self._n_valid, (row >= 0).sum(1)])
         self.nv = jnp.concatenate([self.nv, jnp.asarray(
             np.maximum((row >= 0).sum(1), 1).astype(np.float32))])
         grow = lambda a: jnp.concatenate(  # noqa: E731
@@ -205,8 +225,9 @@ class DeviceStoreBatch:
         row[:store.v_pad] = store.labels
         labels[client] = row
         self.labels = jnp.asarray(labels)
+        self._n_valid[client] = int((row >= 0).sum())
         nv = np.array(self.nv)
-        nv[client] = max(int((row >= 0).sum()), 1)
+        nv[client] = max(int(self._n_valid[client]), 1)
         self.nv = jnp.asarray(nv)
         acc = np.array(self.acc)
         acc[client] = _zero_row_acc(row)
@@ -233,26 +254,40 @@ class DeviceStoreBatch:
         return groups, n_dirty
 
     def _flush_bucket(self, groups, R: int):
-        """One donated scatter+recompute for all groups padded to width R."""
-        K = _pow2(len(groups))
-        groups = groups + [groups[0]] * (K - len(groups))
-        all_clients = (K == len(self.stores)
-                       and all(g[0] == i for i, g in enumerate(groups)))
-        rows = np.zeros((K * R, self.v_max, self.n_classes), np.float32)
-        rmask = np.zeros((K * R,), np.float32)
-        cu = np.zeros((K,), np.int32)
-        slots = np.zeros((K, R), np.int32)
-        for k, (c, blk) in enumerate(groups):
-            s = self.stores[c]
-            cu[k] = c
-            slots[k] = blk + [blk[-1]] * (R - len(blk))
-            rows[k * R:(k + 1) * R, :s.v_pad] = s.preds[slots[k]]
-            rmask[k * R:(k + 1) * R] = s.mask[slots[k]]
-        self.preds, self.pnorm, self.masks, self.acc, self.S = _flush(
-            self.preds, self.pnorm, self.masks, self.acc, self.S,
-            self.labels, self.nv, jnp.asarray(rows), jnp.asarray(rmask),
-            jnp.asarray(cu), jnp.asarray(slots), all_clients=all_clients)
+        """One donated scatter+recompute for all groups padded to width R
+        (`groups` are distinct clients: the useful bytes are theirs)."""
+        mx = self.metrics
+        useful = sum(len(blk) * int(self._n_valid[c])
+                     for c, blk in groups) * self.n_classes * 4
+        with mx.span("store.flush.assemble"):
+            K = _pow2(len(groups))
+            groups = groups + [groups[0]] * (K - len(groups))
+            all_clients = (K == len(self.stores)
+                           and all(g[0] == i for i, g in enumerate(groups)))
+            rows = np.zeros((K * R, self.v_max, self.n_classes), np.float32)
+            rmask = np.zeros((K * R,), np.float32)
+            cu = np.zeros((K,), np.int32)
+            slots = np.zeros((K, R), np.int32)
+            for k, (c, blk) in enumerate(groups):
+                s = self.stores[c]
+                cu[k] = c
+                slots[k] = blk + [blk[-1]] * (R - len(blk))
+                rows[k * R:(k + 1) * R, :s.v_pad] = s.preds[slots[k]]
+                rmask[k * R:(k + 1) * R] = s.mask[slots[k]]
+        host = (rows, rmask, cu, slots)
+        with mx.span("store.flush.upload"):
+            dev = [jnp.asarray(a) for a in host]
+        with mx.span("store.flush.launch"):
+            self.preds, self.pnorm, self.masks, self.acc, self.S = _flush(
+                self.preds, self.pnorm, self.masks, self.acc, self.S,
+                self.labels, self.nv, *dev, all_clients=all_clients)
+        uploaded = sum(a.nbytes for a in host)
         self.n_flushes += 1
+        self.n_bytes_uploaded += uploaded
+        self.n_bytes_useful += useful
+        if mx.enabled:
+            mx.inc("store.upload_bytes", uploaded)
+            mx.inc("store.useful_bytes", useful)
 
     def flush(self):
         """Drain the dirty queues into donated scatter + stats updates.
@@ -266,18 +301,21 @@ class DeviceStoreBatch:
         log M scatters per flush, but one bursty client (e.g. a fresh
         churn join with every slot dirty) no longer inflates the padded
         width of every other client's group."""
-        groups, n_dirty = self._drain()
-        if not groups:
-            return 0
-        buckets = {}
-        for g in groups:
-            # floor the width at 2: an R=1 launch lowers to a matvec whose
-            # fp reduction order differs from the R>=2 matmuls (matmul
-            # widths are bit-stable across R and K), which would break
-            # incremental-vs-one-shot bitwise stat parity
-            buckets.setdefault(max(2, _pow2(len(g[1]))), []).append(g)
-        for R in sorted(buckets):
-            self._flush_bucket(buckets[R], R)
+        mx = self.metrics
+        with mx.span("store.flush"):
+            with mx.span("store.flush.drain"):
+                groups, n_dirty = self._drain()
+            if not groups:
+                return 0
+            buckets = {}
+            for g in groups:
+                # floor the width at 2: an R=1 launch lowers to a matvec
+                # whose fp reduction order differs from the R>=2 matmuls
+                # (matmul widths are bit-stable across R and K), which
+                # would break incremental-vs-one-shot bitwise stat parity
+                buckets.setdefault(max(2, _pow2(len(g[1]))), []).append(g)
+            for R in sorted(buckets):
+                self._flush_bucket(buckets[R], R)
         self.n_rows_scattered += n_dirty
         return n_dirty
 

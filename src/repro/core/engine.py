@@ -66,6 +66,19 @@ class SelectionEngine:
         self.metrics = metrics if metrics is not None else NULL_METRICS
         self.results: Dict[int, dict] = {}   # client -> last selection dict
         self._keys_cache: Dict[tuple, object] = {}  # batch -> PRNG streams
+        self._n_selects = 0                  # the `select_id` of spans
+
+    @property
+    def metrics(self):
+        """The run's registry, shared with the device batch; settable
+        after construction (a benchmark switches it on after warm-up)."""
+        return self._metrics
+
+    @metrics.setter
+    def metrics(self, mx):
+        self._metrics = mx
+        if self.device is not None:
+            self.device.metrics = mx
 
     # ---- membership ---------------------------------------------------
     def _check_width(self, store):
@@ -114,54 +127,73 @@ class SelectionEngine:
         if mx.enabled:
             mx.observe("engine.ga_batch_width", B, t=t)
         batch = ready + [ready[0]] * (B - len(ready))
+        self._n_selects += 1
+        with mx.span("engine.select", select_id=self._n_selects):
+            with mx.span("engine.select.keys"):
+                keys = self._batch_keys(batch)
+            out = self._launch(batch, keys, t)
+            # ONE device->host transfer per result key (a per-client
+            # slicing loop over device arrays costs hundreds of tiny
+            # transfers)
+            with mx.span("engine.select.fetch"):
+                host = {k: np.asarray(v) for k, v in out.items()}
+            with mx.span("engine.select.unpack"):
+                fresh = {}
+                for i, c in enumerate(ready):
+                    res = {k: v[i] for k, v in host.items()}
+                    res["slot_gen"] = self.stores[c].slot_gen.copy()
+                    self.stores[c].note_selection(res["chromosome"] > 0.5, t)
+                    self.results[c] = res
+                    fresh[c] = res
+        return fresh
+
+    def _batch_keys(self, batch):
+        """The batch's per-client PRNG streams, cached by composition."""
         keys = self._keys_cache.get(tuple(batch))
         if keys is None:
             if len(self._keys_cache) >= 128:   # churn can produce a new
                 self._keys_cache.clear()       # composition per tick —
             keys = client_keys(self.seed, np.asarray(batch, np.uint32))
             self._keys_cache[tuple(batch)] = keys  # keep the cache bounded
-        if self.device is not None:
-            # incremental path: scatter only the dirty rows, then gather
-            # the batch and its cached stats on device (DESIGN.md §7);
-            # a whole-fleet batch in natural order is served from the
-            # resident buffers directly (identity gather elided)
-            if self.device.preds.shape[0] != len(self.stores):
-                raise RuntimeError(
-                    "engine.stores grew without the device mirror — "
-                    "admit late joiners through engine.add_store()")
-            if mx.enabled:
-                with mx.stopwatch("engine.flush_wall_s")(t=t):
-                    n_dirty = self.device.flush()
-                mx.observe("engine.flush_dirty_slots", n_dirty, t=t)
-            else:
-                self.device.flush()
+        return keys
+
+    def _launch(self, batch, keys, t: float):
+        """Flush, gather and dispatch the batch's selection program; the
+        results are still on the device."""
+        mx = self.metrics
+        if self.device is None:
+            # legacy restack path: re-stack + re-derive everything
+            with mx.span("engine.select.gather"):
+                preds, labels, masks = stack_stores(self.stores, batch,
+                                                    v_to=self._v_max)
+            with mx.span("engine.select.launch"):
+                return select_ensembles(
+                    jnp.asarray(preds), jnp.asarray(labels), self.nsga,
+                    use_kernel=self.use_kernel, keys=keys,
+                    model_mask=jnp.asarray(masks))
+        # incremental path: scatter only the dirty rows, then gather the
+        # batch and its cached stats on device (DESIGN.md §7); a
+        # whole-fleet batch in natural order is served from the resident
+        # buffers directly (identity gather elided)
+        dev = self.device
+        if dev.preds.shape[0] != len(self.stores):
+            raise RuntimeError(
+                "engine.stores grew without the device mirror — "
+                "admit late joiners through engine.add_store()")
+        n_dirty = dev.flush()
+        if mx.enabled:
+            mx.observe("engine.flush_dirty_slots", n_dirty, t=t)
+        with mx.span("engine.select.gather"):
             if batch == list(range(len(self.stores))):
-                dev = self.device
                 preds, labels, masks, acc, S = (dev.preds, dev.labels,
                                                 dev.masks, dev.acc, dev.S)
             else:
-                preds, labels, masks, acc, S = self.device.gather(batch)
-            out = select_ensembles_from_stats(
+                preds, labels, masks, acc, S = dev.gather(batch)
+        with mx.span("engine.select.launch"):
+            return select_ensembles_from_stats(
                 acc, S, preds, labels, self.nsga,
-                use_kernel=self.use_kernel, keys=keys, model_mask=masks)
-        else:
-            # legacy restack path: re-stack + re-derive everything
-            preds, labels, masks = stack_stores(self.stores, batch,
-                                                v_to=self._v_max)
-            out = select_ensembles(jnp.asarray(preds), jnp.asarray(labels),
-                                   self.nsga, use_kernel=self.use_kernel,
-                                   keys=keys, model_mask=jnp.asarray(masks))
-        # ONE device->host transfer per result key (a per-client slicing
-        # loop over device arrays costs hundreds of tiny transfers)
-        host = {k: np.asarray(v) for k, v in out.items()}
-        fresh = {}
-        for i, c in enumerate(ready):
-            res = {k: v[i] for k, v in host.items()}
-            res["slot_gen"] = self.stores[c].slot_gen.copy()
-            self.stores[c].note_selection(res["chromosome"] > 0.5, t)
-            self.results[c] = res
-            fresh[c] = res
-        return fresh
+                use_kernel=self.use_kernel, keys=keys,
+                model_mask=masks)
 
     def refresh_validation(self, c: int, x_val, y_val, preds) -> None:
         """Serving-time drift refresh (DESIGN.md §14): swap client c's

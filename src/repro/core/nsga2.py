@@ -20,6 +20,10 @@ Two entry points, one program (DESIGN.md §3):
 
 Each client gets its OWN PRNG stream (`keys[(N, 2)]`); clients no longer
 share one GA random sequence through `NSGAConfig.seed`.
+
+The batched run carries device scopes in its op names (DESIGN.md §11):
+`ga`, with `ga/init`, `ga/eval` (every objective evaluation), `ga/rank`
+(front peeling and crowding), `ga/breed` and `ga/survive`.
 """
 from __future__ import annotations
 
@@ -147,11 +151,11 @@ def _breed(pop, ranks, crowd, key_g, cfg: NSGAConfig, valid_mask=None):
     return child
 
 
-def _survival_order(aobjs):
-    """(2P, n_obj) -> survival sort order (rank asc, crowding desc)."""
-    aranks = nondominated_rank(aobjs)
-    acrowd = crowding_distance(aobjs, aranks)
-    return jnp.argsort(aranks.astype(jnp.float32) * BIG - acrowd), aranks, acrowd
+def _rank_crowd(objs):
+    """(N, P, n_obj) -> per-client (ranks, crowding), device scope `rank`."""
+    with jax.named_scope("rank"):
+        ranks = jax.vmap(nondominated_rank)(objs)
+        return ranks, jax.vmap(crowding_distance)(objs, ranks)
 
 
 def run_nsga2(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
@@ -191,39 +195,52 @@ def run_nsga2_batched(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
     survival over the combined 2P pool.
     """
     P, M, k = cfg.pop_size, n_models, cfg.k
-    sub = jax.vmap(lambda kk: jax.random.split(kk, 3))(keys)  # (N, 3, 2)
-    key_loop, k0, k1 = sub[:, 0], sub[:, 1], sub[:, 2]
-    if valid_mask is None:
-        pop = jax.vmap(lambda a, b: _init_population(a, b, P, M, k, None,
-                                                     init_pop))(k0, k1)
-    else:
-        pop = jax.vmap(lambda a, b, vm: _init_population(a, b, P, M, k, vm,
-                                                         init_pop))(k0, k1, valid_mask)
+    with jax.named_scope("ga"):
+        def evaluate(pop):
+            with jax.named_scope("eval"):
+                return eval_fn(pop)
 
-    def breed_one(pop_c, ranks_c, crowd_c, key_c, vm_c):
-        return _breed(pop_c, ranks_c, crowd_c, key_c, cfg, vm_c)
+        with jax.named_scope("init"):
+            sub = jax.vmap(lambda kk: jax.random.split(kk, 3))(keys)
+            key_loop, k0, k1 = sub[:, 0], sub[:, 1], sub[:, 2]
+            if valid_mask is None:
+                pop = jax.vmap(lambda a, b: _init_population(
+                    a, b, P, M, k, None, init_pop))(k0, k1)
+            else:
+                pop = jax.vmap(lambda a, b, vm: _init_population(
+                    a, b, P, M, k, vm, init_pop))(k0, k1, valid_mask)
+            gkeys = jax.vmap(lambda kk: jax.random.split(
+                kk, cfg.generations))(key_loop)
+            gkeys = jnp.swapaxes(gkeys, 0, 1)  # (G, N, 2)
 
-    def gen(pop, keys_g):  # pop: (N, P, M); keys_g: (N, 2)
-        objs = eval_fn(pop)                                   # (N, P, n_obj)
-        ranks = jax.vmap(nondominated_rank)(objs)
-        crowd = jax.vmap(crowding_distance)(objs, ranks)
-        if valid_mask is None:
-            child = jax.vmap(lambda p, r, c, kk: _breed(p, r, c, kk, cfg))(
-                pop, ranks, crowd, keys_g)
-        else:
-            child = jax.vmap(breed_one)(pop, ranks, crowd, keys_g, valid_mask)
-        allp = jnp.concatenate([pop, child], axis=1)          # (N, 2P, M)
-        aobjs = eval_fn(allp)
-        order = jax.vmap(lambda o: _survival_order(o)[0])(aobjs)
-        pop = jnp.take_along_axis(allp, order[:, :P, None], axis=1)
-        return pop, None
+        def breed_one(pop_c, ranks_c, crowd_c, key_c, vm_c):
+            return _breed(pop_c, ranks_c, crowd_c, key_c, cfg, vm_c)
 
-    gkeys = jax.vmap(lambda kk: jax.random.split(kk, cfg.generations))(key_loop)
-    gkeys = jnp.swapaxes(gkeys, 0, 1)  # (G, N, 2)
-    pop, _ = jax.lax.scan(gen, pop, gkeys)
-    objs = eval_fn(pop)
-    ranks = jax.vmap(nondominated_rank)(objs)
-    return {"pop": pop, "objs": objs, "ranks": ranks}
+        def gen(pop, keys_g):  # pop: (N, P, M); keys_g: (N, 2)
+            objs = evaluate(pop)                              # (N, P, n_obj)
+            ranks, crowd = _rank_crowd(objs)
+            with jax.named_scope("breed"):
+                if valid_mask is None:
+                    child = jax.vmap(
+                        lambda p, r, c, kk: _breed(p, r, c, kk, cfg))(
+                            pop, ranks, crowd, keys_g)
+                else:
+                    child = jax.vmap(breed_one)(pop, ranks, crowd, keys_g,
+                                                valid_mask)
+            with jax.named_scope("survive"):
+                allp = jnp.concatenate([pop, child], axis=1)  # (N, 2P, M)
+            aranks, acrowd = _rank_crowd(evaluate(allp))
+            with jax.named_scope("survive"):     # rank asc, crowding desc
+                order = jax.vmap(jnp.argsort)(
+                    aranks.astype(jnp.float32) * BIG - acrowd)
+                pop = jnp.take_along_axis(allp, order[:, :P, None], axis=1)
+            return pop, None
+
+        pop, _ = jax.lax.scan(gen, pop, gkeys)
+        objs = evaluate(pop)
+        with jax.named_scope("rank"):
+            ranks = jax.vmap(nondominated_rank)(objs)
+        return {"pop": pop, "objs": objs, "ranks": ranks}
 
 
 def client_keys(seed: int, client_ids) -> jnp.ndarray:

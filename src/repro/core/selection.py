@@ -92,8 +92,9 @@ def _ga_stage(acc, S, probs_val, labels_val, nsga: NSGAConfig,
             return jnp.stack([st, dv], axis=2)
 
     out = run_nsga2_batched(eval_fn, M, nsga, keys, valid_mask=model_mask)
-    return jax.vmap(_pick_winner)(out["pop"], out["objs"], out["ranks"],
-                                  probs_val, labels_val, acc)
+    with jax.named_scope("vote"):     # the device scope of the winner pick
+        return jax.vmap(_pick_winner)(out["pop"], out["objs"], out["ranks"],
+                                      probs_val, labels_val, acc)
 
 
 @partial(jax.jit, static_argnames=("nsga", "use_kernel"))

@@ -6,7 +6,7 @@ addressed by a metric NAME plus an optional LABEL SET
 
   counter     monotone accumulator (`inc`) — messages, bytes, accepts;
   gauge       last-write-wins level (`set`) — coverage fraction, t_full;
-  series      pure time-series samples (`observe`) — flush wall time,
+  series      pure time-series samples (`observe`) — select wall time,
               GA batch width, select-batch width.
 
 Every mutation may carry the VIRTUAL time `t` of the simulated event it
@@ -25,17 +25,31 @@ attribute unconditionally — instrumented code never branches on "is
 observability wired in", it just calls.
 
 `Stopwatch` is the one wall-clock bracketing helper (start/stop or
-context manager): the scheduler's event-loop `perf` phases and the
-engine's flush timing both derive from it, so there is exactly one
-`time.perf_counter()` idiom in the codebase. A stopwatch bound to a
-registry also records each lap as a series observation.
+context manager): the scheduler's event-loop `perf` phases derive from
+it, so there is exactly one `time.perf_counter()` idiom in the codebase.
+A stopwatch bound to a registry also records each lap as a series
+observation.
+
+`Metrics.span(name, **ids)` is the host-span form of the same clock: on
+an enabled registry it opens a `jax.profiler.TraceAnnotation` (so the
+span lands on the profiler's clock beside the device's ops), keeps
+`(name, parent, ids, start, end)` in a bounded buffer, and totals each
+name's duration and self time (duration minus what its child spans
+cover); the frame reports both. Spans time the HOST: what a span
+brackets is dispatch, not device completion, unless the code inside it
+waits for the device. On a disabled registry `span` returns one shared
+no-op context manager.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import math
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 _KINDS = ("counter", "gauge", "series")
 
@@ -108,6 +122,57 @@ class MetricsFrame:
                    meta=dict(d.get("meta") or {}))
 
 
+class SpanRecord(NamedTuple):
+    """One closed host span: `start`/`end` are `time.perf_counter()`
+    seconds, `parent` the enclosing span's name (None at top level)."""
+    name: str
+    parent: Optional[str]
+    ids: dict
+    start: float
+    end: float
+
+
+# the disabled registry's span: shared, stateless, reusable
+_NULL_SPAN = contextlib.nullcontext()
+# closed spans a registry keeps (oldest dropped first; totals keep all)
+SPAN_BUFFER = 1 << 16
+# the stats a span name reports in a frame: metric_key(name, stat=...)
+SPAN_STATS = ("count", "total_s", "self_s")
+
+
+class _Span:
+    """An open span of an enabled registry (see `Metrics.span`)."""
+    __slots__ = ("_mx", "_name", "_ids", "_ann", "_t0", "_child")
+
+    def __init__(self, mx: "Metrics", name: str, ids: dict):
+        self._mx, self._name, self._ids = mx, name, ids
+
+    def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(self._name, **self._ids)
+        self._ann.__enter__()
+        self._child = 0.0
+        self._mx._open.append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        mx = self._mx
+        mx._open.pop()
+        parent = mx._open[-1] if mx._open else None
+        dur = t1 - self._t0
+        if parent is not None:
+            parent._child += dur
+        tot = mx._span_totals.setdefault(self._name, [0, 0.0, 0.0])
+        tot[0] += 1
+        tot[1] += dur
+        tot[2] += max(0.0, dur - self._child)
+        mx._spans.append(SpanRecord(
+            self._name, None if parent is None else parent._name,
+            self._ids, self._t0, t1))
+        self._ann.__exit__(*exc)
+
+
 class Metrics:
     """One run's metrics registry. `enabled=False` instances are inert
     (every mutator returns immediately) — the no-op path instrumented
@@ -117,6 +182,9 @@ class Metrics:
         self.enabled = enabled
         self.resolution = float(resolution)
         self._instruments: Dict[str, _Instrument] = {}
+        self._open: List[_Span] = []          # the open spans, innermost last
+        self._spans = collections.deque(maxlen=SPAN_BUFFER)
+        self._span_totals: Dict[str, list] = {}   # name -> [n, total, self]
 
     # ---- internals ----------------------------------------------------
     def _get(self, kind: str, name: str, labels: dict) -> _Instrument:
@@ -176,9 +244,31 @@ class Metrics:
         return Stopwatch(metrics=self if self.enabled else None,
                          name=name, **labels)
 
+    def span(self, name: str, **ids):
+        """A host span (context manager): on an enabled registry it is
+        also a profiler `TraceAnnotation` carrying `ids`, and is kept in
+        the span buffer and the per-name totals. A disabled registry
+        returns one shared no-op context manager."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _Span(self, name, ids)
+
+    def spans(self) -> List[SpanRecord]:
+        """The closed spans still in the bounded buffer, oldest first
+        (a child closes, so appears, before its parent)."""
+        return list(self._spans)
+
+    def span_totals(self) -> Dict[str, Tuple[int, float, float]]:
+        """name -> (count, total seconds, self seconds) over every span
+        closed so far, whether or not it is still in the buffer."""
+        return {k: tuple(v) for k, v in self._span_totals.items()}
+
     # ---- collection ---------------------------------------------------
     def frame(self, meta: Optional[dict] = None) -> MetricsFrame:
         scalars = {k: i.value for k, i in sorted(self._instruments.items())}
+        for name, tot in sorted(self._span_totals.items()):
+            for stat, v in zip(SPAN_STATS, tot):
+                scalars[metric_key(name, {"stat": stat})] = v
         series = {k: [[t, v] for t, v in i.samples]
                   for k, i in sorted(self._instruments.items())
                   if i.samples}

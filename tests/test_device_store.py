@@ -15,6 +15,7 @@ from repro.core.nsga2 import NSGAConfig, client_keys
 from repro.core.selection import (select_ensembles,
                                   select_ensembles_from_stats,
                                   selection_stats)
+from repro.obs.metrics import NULL_METRICS, Metrics
 
 N, CAP, V, C = 4, 8, 96, 5
 CFG = NSGAConfig(pop_size=16, generations=6, k=3, seed=3)
@@ -339,3 +340,124 @@ def test_predictions_falls_back_for_paramless_entries():
     out = store.predictions(np.zeros((5, 2), np.float32))
     assert sorted(calls) == [0, 1]   # shipped closures: loop path
     assert out.shape == (3, 5, C)
+
+
+# ------------------------------------------------- observability (§11)
+
+def test_flush_counts_uploaded_and_useful_bytes():
+    """Uploaded bytes are the padded host block plus its index arrays;
+    useful bytes are the distinct dirty slots' real validation samples
+    (sum of n_val·C·4), whatever the R/K repeats and the V pad."""
+    rng = np.random.default_rng(5)
+    n_val = [60, 96, 80, 40]
+    stores = [PredictionStore(c, CAP, np.zeros((n, 2), np.float32),
+                              rng.integers(0, C, n), C, v_pad=V)
+              for c, n in enumerate(n_val)]
+    dev = DeviceStoreBatch(stores)
+    assert dev.flush() == 0
+    assert dev.n_bytes_uploaded == dev.n_bytes_useful == 0
+    dirty = {0: [0, 1, 2], 1: [0, 1, 2, 3], 2: [5, 6, 7], 3: [4]}
+    for c, slots in dirty.items():
+        for s in slots:
+            stores[c].add(_entry(s), preds=_rand_preds(rng))
+    stores[0].add(_entry(1), preds=_rand_preds(rng))   # same slot again
+    mx = Metrics()
+    dev.metrics = mx
+    assert dev.flush() == 11
+    # R=4 holds clients 0-2 (K=3 padded to 4); R=2 holds client 3 (K=1)
+    n_rows = 4 * 4 + 1 * 2
+    rows = n_rows * dev.v_max * C * 4
+    index = n_rows * 4 * 2 + (4 + 1) * 4           # rmask, slots; cu
+    useful = (3 * 60 + 4 * 96 + 3 * 80 + 1 * 40) * C * 4
+    assert dev.n_bytes_uploaded == rows + index
+    assert dev.n_bytes_useful == useful
+    scalars = mx.frame().scalars
+    assert scalars["store.upload_bytes"] == rows + index
+    assert scalars["store.useful_bytes"] == useful
+
+
+def _spans_after_selects(metrics):
+    stores, rng = _fresh_stores(seed=4)
+    eng = SelectionEngine(stores, CFG, ensemble_k=CFG.k, metrics=metrics)
+    _churn(stores, rng)
+    out = [eng.select([0, 2], t=1.0)]
+    _churn(stores, rng, n_ops=10)
+    out.append(eng.select(t=2.0))
+    return out
+
+
+def test_engine_select_records_the_span_tree():
+    mx = Metrics()
+    _spans_after_selects(mx)
+    recs = mx.spans()
+    assert {(r.name, r.parent) for r in recs} == {
+        ("engine.select", None),
+        ("engine.select.keys", "engine.select"),
+        ("store.flush", "engine.select"),
+        ("store.flush.drain", "store.flush"),
+        ("store.flush.assemble", "store.flush"),
+        ("store.flush.upload", "store.flush"),
+        ("store.flush.launch", "store.flush"),
+        ("engine.select.gather", "engine.select"),
+        ("engine.select.launch", "engine.select"),
+        ("engine.select.fetch", "engine.select"),
+        ("engine.select.unpack", "engine.select")}
+    assert [r.ids for r in recs if r.name == "engine.select"] == [
+        {"select_id": 1}, {"select_id": 2}]
+    for r in recs:
+        assert r.start <= r.end
+    # the host order of a select: PRNG keys, then the flush, then dispatch
+    first = [r for r in recs if r.parent == "engine.select"][:6]
+    assert [r.name for r in sorted(first, key=lambda r: r.start)] == [
+        "engine.select.keys", "store.flush", "engine.select.gather",
+        "engine.select.launch", "engine.select.fetch", "engine.select.unpack"]
+    # a child lies inside its parent, and self times add up to it
+    tot = mx.span_totals()
+    assert all(s >= 0.0 for _, _, s in tot.values())
+    for parent in ("engine.select", "store.flush"):
+        kids = sum(t for name, (_, t, _) in tot.items()
+                   if name.startswith(parent + ".")
+                   or (parent == "engine.select" and name == "store.flush"))
+        assert tot[parent][2] + kids == pytest.approx(tot[parent][1],
+                                                      rel=1e-9, abs=1e-12)
+    scalars = mx.frame().scalars
+    assert scalars["engine.select{stat=count}"] == 2
+    assert scalars["store.flush{stat=total_s}"] == tot["store.flush"][1]
+
+
+def test_spans_change_no_result_and_the_null_registry_keeps_none():
+    on = _spans_after_selects(Metrics())
+    off = _spans_after_selects(NULL_METRICS)
+    for a, b in zip(on, off):
+        assert sorted(a) == sorted(b)
+        for c in a:
+            for k in ("chromosome", "val_accuracy", "pop", "objs",
+                      "pareto_mask", "member_acc"):
+                np.testing.assert_array_equal(a[c][k], b[c][k])
+    assert NULL_METRICS.spans() == [] and NULL_METRICS.span_totals() == {}
+
+
+def test_compiled_programs_carry_the_device_scopes():
+    """The scopes a profiler trace is reduced by are in the optimized
+    HLO's op names: a refactor that drops one fails here."""
+    from repro.core.device_store import _flush, _gather
+    stores, rng = _fresh_stores(seed=2)
+    _churn(stores, rng)
+    dev = DeviceStoreBatch(stores)
+    K, R = 2, 4
+    rows = jnp.zeros((K * R, dev.v_max, C), jnp.float32)
+    flush = _flush.lower(
+        dev.preds, dev.pnorm, dev.masks, dev.acc, dev.S, dev.labels, dev.nv,
+        rows, jnp.ones((K * R,), jnp.float32), jnp.arange(K, dtype=jnp.int32),
+        jnp.zeros((K, R), jnp.int32)).compile().as_text()
+    assert "/flush/scatter/" in flush and "/flush/gram/" in flush
+    idx = jnp.arange(2, dtype=jnp.int32)
+    gather = _gather.lower(dev.preds, dev.labels, dev.masks, dev.acc, dev.S,
+                           idx).compile().as_text()
+    assert "/gather/" in gather
+    preds, labels, masks, acc, S = dev.gather([0, 1])
+    select = select_ensembles_from_stats.lower(
+        acc, S, preds, labels, CFG, keys=client_keys(0, [0, 1]),
+        model_mask=masks).compile().as_text()
+    for scope in ("/ga/init/", "/ga/eval/", "/ga/rank/", "/vote/"):
+        assert scope in select, scope

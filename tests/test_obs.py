@@ -116,6 +116,31 @@ def test_stopwatch_accumulates_and_records():
     assert len(mx.frame().series["w"]) == 2
 
 
+def test_span_buffer_is_bounded_and_totals_count_every_span(monkeypatch):
+    import repro.obs.metrics as obs_metrics
+    monkeypatch.setattr(obs_metrics, "SPAN_BUFFER", 3)
+    mx = Metrics()
+    for i in range(5):
+        with mx.span("outer", i=i):
+            with mx.span("inner"):
+                pass
+    recs = mx.spans()
+    assert len(recs) == 3                      # the last three to close
+    assert recs[-1].name == "outer" and recs[-1].ids == {"i": 4}
+    assert recs[-2].parent == "outer"
+    n, total, self_s = mx.span_totals()["outer"]
+    assert n == 5 and 0.0 <= self_s <= total
+    scalars = mx.frame().scalars
+    assert scalars["outer{stat=count}"] == 5
+    assert scalars["inner{stat=count}"] == 5
+    assert scalars["outer{stat=self_s}"] == self_s
+    # the disabled registry allocates no span and records nothing
+    assert NULL_METRICS.span("outer") is NULL_METRICS.span("other", i=1)
+    with NULL_METRICS.span("outer"):
+        pass
+    assert NULL_METRICS.frame().names() == set()
+
+
 def test_frame_json_roundtrip():
     mx = Metrics()
     # replint: ok[OBS-PARITY] fixture name for the roundtrip test, not a real series
@@ -298,7 +323,7 @@ def test_engine_metrics_series():
         "seed": 1})
     res = run(spec)
     names = res.metrics.names()
-    for k in ("engine.ga_batch_width", "engine.flush_wall_s",
+    for k in ("engine.ga_batch_width", "store.flush{stat=total_s}",
               "engine.flush_dirty_slots", "engine.select_batch_width",
               "engine.select_wall_s"):
         assert k in names, k
