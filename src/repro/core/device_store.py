@@ -12,8 +12,8 @@ incrementally (DESIGN.md §7):
   `PredictionStore.dirty_seq` slot→event-id map, drained via per-batch
   cursors so several device mirrors can track one fleet independently);
 - `flush()` drains those events into ONE jitted donated-buffer scatter:
-  only the changed `(V, C)` rows cross the host→device boundary
-  (`.at[ci, si].set`, the batched `dynamic_update_slice`), and only the
+  only the changed `(V, C)` rows cross the host→device boundary, once
+  each (`.at[ci, si].set`, the batched `dynamic_update_slice`), and only the
   affected `acc[c, slot]` entries and `S[c, slot, :]` / `S[c, :, slot]`
   row/column pairs are recomputed — `O(dirty · M · V · C)` instead of the
   full `O(N · M² · V · C)` rebuild;
@@ -37,17 +37,24 @@ Dirty slots are grouped per client; the group count and the per-client
 slot width are each padded to the next power of two (repeating groups /
 slots — scatter and recompute are idempotent), so an async run compiles
 O(log N · log M) flush variants, mirroring the engine's client-batch
-padding. When every client is dirty the per-group block gather is elided
-and the matmul reads the resident normalized tensor directly.
+padding. The padding never crosses the host→device boundary: each
+distinct dirty row is copied once into a host block the batch keeps
+across flushes (U rows, U the next power of two of the distinct rows),
+and `_expand` pads it on the device through an int32 index of the K·R
+padded positions. When every client is dirty the per-group block gather
+is elided and the matmul reads the resident normalized tensor directly.
 
 Observability (DESIGN.md §11): `n_bytes_uploaded` counts the host→device
-bytes of every flush (the padded row block and its index arrays), and
+bytes of every flush (the compact row block and its index arrays), and
 `n_bytes_useful` the part of them that carries real validation samples
-of distinct dirty slots. With an enabled `metrics` registry the flush
+of distinct dirty slots; `n_rows_uploaded` counts the rows of the
+uploaded blocks (U) and `n_rows_expanded` the padded rows the device
+expands them to (K·R). With an enabled `metrics` registry the flush
 opens host spans (`store.flush` and its drain/assemble/upload/launch
-children) and mirrors both byte counts; the jitted programs carry device
-scopes (`flush`, `flush/scatter`, `flush/gram`, `gather`) in their op
-names.
+children) and mirrors the four counts (`store.upload_bytes`,
+`store.useful_bytes`, `store.upload_rows`, `store.expanded_rows`); the
+jitted programs carry device scopes (`flush`, `flush/scatter`,
+`flush/gram`, `gather`) in their op names.
 """
 from __future__ import annotations
 
@@ -78,6 +85,18 @@ def _zero_row_acc(label_row: np.ndarray) -> np.float32:
     valid = label_row >= 0
     nv = max(int(valid.sum()), 1)
     return np.float32(int(((label_row == 0) & valid).sum())) / np.float32(nv)
+
+
+@jax.jit
+def _expand(rows, row_mask, src):
+    """The padded dirty-row block from the compact upload: rows (U, V, C)
+    and row_mask (U,) hold each distinct dirty row once, and src (K·R,)
+    maps every padded (group, slot) position to its row. A program of
+    its own, so that `_flush` compiles and rounds exactly as it does on
+    a padded block uploaded whole: inside `_flush` the TPU lays the
+    gathered block out otherwise, and its row norms round differently."""
+    with jax.named_scope("flush"), jax.named_scope("scatter"):
+        return rows[src], row_mask[src]
 
 
 @partial(jax.jit, static_argnames=("all_clients",),
@@ -154,9 +173,12 @@ class DeviceStoreBatch:
         self._cursor: List[int] = []       # per-client dirty-log position
         self.n_flushes = 0
         self.n_rows_scattered = 0          # perf counters (bench/DESIGN §7)
+        self.n_rows_uploaded = 0           # rows of the uploaded blocks
+        self.n_rows_expanded = 0           # ... after the K/R padding
         self.n_bytes_uploaded = 0          # host->device bytes of flushes
         self.n_bytes_useful = 0            # ... that are real dirty rows
         self.metrics = NULL_METRICS        # the engine's registry (§11)
+        self._stage = {}                   # U -> host upload block
         labels = np.full((len(stores), self.v_max), -1, np.int32)
         self.preds = jnp.zeros((len(stores), cap, self.v_max, C), jnp.float32)
         self.pnorm = jnp.zeros_like(self.preds)  # cached normalized mirror
@@ -253,39 +275,68 @@ class DeviceStoreBatch:
                 n_dirty += len(slots)
         return groups, n_dirty
 
+    def _staging(self, U: int) -> np.ndarray:
+        """The host block for a U-row upload, kept across flushes. The
+        runtime may read (and on the CPU alias) it after the upload call
+        returns, so a block is rewritten only once the flush that read it
+        has run: before reusing one, wait for the newest `S`, which every
+        earlier flush precedes (each takes the last one's `S`)."""
+        buf = self._stage.get(U)
+        if buf is None:
+            buf = self._stage[U] = np.empty(
+                (U, self.v_max, self.n_classes), np.float32)
+        else:
+            self.S.block_until_ready()
+        return buf
+
     def _flush_bucket(self, groups, R: int):
         """One donated scatter+recompute for all groups padded to width R
-        (`groups` are distinct clients: the useful bytes are theirs)."""
+        (`groups` are distinct clients: the useful bytes are theirs).
+        Each distinct dirty row is uploaded once, in a block of U =
+        pow2(rows) rows, and `_expand` pads it on the device."""
         mx = self.metrics
         useful = sum(len(blk) * int(self._n_valid[c])
                      for c, blk in groups) * self.n_classes * 4
         with mx.span("store.flush.assemble"):
-            K = _pow2(len(groups))
-            groups = groups + [groups[0]] * (K - len(groups))
-            all_clients = (K == len(self.stores)
+            G, K = len(groups), _pow2(len(groups))
+            all_clients = (K == G == len(self.stores)
                            and all(g[0] == i for i, g in enumerate(groups)))
-            rows = np.zeros((K * R, self.v_max, self.n_classes), np.float32)
-            rmask = np.zeros((K * R,), np.float32)
+            U = _pow2(sum(len(blk) for _, blk in groups))
+            rows = self._staging(U)
+            rmask = np.zeros((U,), np.float32)
             cu = np.zeros((K,), np.int32)
             slots = np.zeros((K, R), np.int32)
+            src = np.zeros((K, R), np.int32)
+            i = 0
             for k, (c, blk) in enumerate(groups):
-                s = self.stores[c]
+                s, n = self.stores[c], len(blk)
                 cu[k] = c
-                slots[k] = blk + [blk[-1]] * (R - len(blk))
-                rows[k * R:(k + 1) * R, :s.v_pad] = s.preds[slots[k]]
-                rmask[k * R:(k + 1) * R] = s.mask[slots[k]]
-        host = (rows, rmask, cu, slots)
+                slots[k] = blk + [blk[-1]] * (R - n)
+                src[k] = i + np.minimum(np.arange(R), n - 1)
+                for j, slot in enumerate(blk):
+                    rows[i + j, :s.v_pad] = s.preds[slot]
+                rows[i:i + n, s.v_pad:] = 0.0
+                rmask[i:i + n] = s.mask[blk]
+                i += n
+            # the group count is padded by repeating group 0
+            cu[G:], slots[G:], src[G:] = cu[0], slots[0], src[0]
+        host = (rows, rmask, src.reshape(-1), cu, slots)
         with mx.span("store.flush.upload"):
             dev = [jnp.asarray(a) for a in host]
         with mx.span("store.flush.launch"):
             self.preds, self.pnorm, self.masks, self.acc, self.S = _flush(
                 self.preds, self.pnorm, self.masks, self.acc, self.S,
-                self.labels, self.nv, *dev, all_clients=all_clients)
+                self.labels, self.nv, *_expand(*dev[:3]), *dev[3:],
+                all_clients=all_clients)
         uploaded = sum(a.nbytes for a in host)
         self.n_flushes += 1
+        self.n_rows_uploaded += U
+        self.n_rows_expanded += K * R
         self.n_bytes_uploaded += uploaded
         self.n_bytes_useful += useful
         if mx.enabled:
+            mx.inc("store.upload_rows", U)
+            mx.inc("store.expanded_rows", K * R)
             mx.inc("store.upload_bytes", uploaded)
             mx.inc("store.useful_bytes", useful)
 

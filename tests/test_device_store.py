@@ -9,7 +9,7 @@ import pytest
 
 from repro.core.bench import (BenchEntry, PredictionStore,
                               StreamingPredictionStore, stack_stores)
-from repro.core.device_store import DeviceStoreBatch
+from repro.core.device_store import DeviceStoreBatch, _expand, _flush, _pow2
 from repro.core.engine import SelectionEngine
 from repro.core.nsga2 import NSGAConfig, client_keys
 from repro.core.selection import (select_ensembles,
@@ -234,9 +234,9 @@ def test_donation_safety_no_use_after_donate():
 def test_flush_is_donated():
     """The jitted flush really marks its five mutable buffers as donated
     (input-output aliased — the in-place device update the tentpole is
-    named for); labels, nv, and the dirty rows are not."""
-    from repro.core.device_store import _flush
-    n, m, v, c, k, r = 2, 4, 8, 3, 1, 2
+    named for); labels, nv, and the dirty rows are not, and the
+    expansion of the compact upload that feeds it donates nothing."""
+    n, m, v, c, k, r, u = 2, 4, 8, 3, 1, 2, 1
     args = (jnp.zeros((n, m, v, c)), jnp.zeros((n, m, v, c)),
             jnp.zeros((n, m)), jnp.zeros((n, m)), jnp.zeros((n, m, m)),
             jnp.zeros((n, v), jnp.int32), jnp.ones((n,)),
@@ -248,6 +248,10 @@ def test_flush_is_donated():
         assert f"%arg{i}: " in main and "aliasing_output" in \
             main.split(f"%arg{i}: ")[1].split("%arg")[0]
     assert "aliasing_output" not in main.split("%arg5: ")[1]
+    # the expansion of the compact upload donates nothing
+    expand = _expand.lower(jnp.zeros((u, v, c)), jnp.zeros((u,)),
+                           jnp.zeros((k * r,), jnp.int32)).as_text()
+    assert "aliasing_output" not in expand
 
 
 # ------------------------------------------------- v_max guard (churn join)
@@ -345,7 +349,8 @@ def test_predictions_falls_back_for_paramless_entries():
 # ------------------------------------------------- observability (§11)
 
 def test_flush_counts_uploaded_and_useful_bytes():
-    """Uploaded bytes are the padded host block plus its index arrays;
+    """Uploaded bytes are the compact row block (each distinct dirty row
+    once, padded to a power of two of rows) plus its index arrays;
     useful bytes are the distinct dirty slots' real validation samples
     (sum of n_val·C·4), whatever the R/K repeats and the V pad."""
     rng = np.random.default_rng(5)
@@ -364,16 +369,189 @@ def test_flush_counts_uploaded_and_useful_bytes():
     mx = Metrics()
     dev.metrics = mx
     assert dev.flush() == 11
-    # R=4 holds clients 0-2 (K=3 padded to 4); R=2 holds client 3 (K=1)
-    n_rows = 4 * 4 + 1 * 2
+    # R=4 holds clients 0-2 (10 rows -> U=16; K=3 padded to 4), R=2
+    # holds client 3 (1 row -> U=1; K=1)
+    n_rows, n_padded = 16 + 1, 4 * 4 + 1 * 2
     rows = n_rows * dev.v_max * C * 4
-    index = n_rows * 4 * 2 + (4 + 1) * 4           # rmask, slots; cu
+    index = n_rows * 4 + (4 + 1) * 4 + n_padded * 4 * 2  # rmask; cu; slots, src
     useful = (3 * 60 + 4 * 96 + 3 * 80 + 1 * 40) * C * 4
     assert dev.n_bytes_uploaded == rows + index
     assert dev.n_bytes_useful == useful
     scalars = mx.frame().scalars
     assert scalars["store.upload_bytes"] == rows + index
     assert scalars["store.useful_bytes"] == useful
+
+
+@pytest.mark.parametrize("dirty,uploaded,expanded", [
+    # 4 clients x 5 rows (the async cell's shape): K=4, R=8, U=32
+    ({0: range(5), 1: range(5), 2: range(5), 3: range(5)}, 32, 32),
+    # 3 full clients: K padded 3 -> 4, R=8; 24 rows -> U=32
+    ({0: range(CAP), 1: range(CAP), 3: range(CAP)}, 32, 32),
+    # 3 clients x 1 row: R floored at 2, K=4; 3 rows -> U=4
+    ({0: [2], 1: [0], 2: [7]}, 4, 8),
+    # a 3-row client pads R to 4 and a 1-row one sits in the R=2 bucket
+    ({1: [0, 3, 5], 2: [6]}, 4 + 1, 4 + 2),
+])
+def test_flush_counts_uploaded_and_expanded_rows(dirty, uploaded, expanded):
+    """`n_rows_uploaded` sums U, the distinct dirty rows rounded up to a
+    power of two, and `n_rows_expanded` the K·R padded rows the device
+    expands them to; the registry mirrors both."""
+    stores, rng = _fresh_stores(seed=13)
+    dev = DeviceStoreBatch(stores)
+    mx = Metrics()
+    dev.metrics = mx
+    for c, slots in dirty.items():
+        for s in slots:
+            stores[c].add(_entry(s), preds=_rand_preds(rng))
+    assert dev.flush() == sum(len(list(v)) for v in dirty.values())
+    assert (dev.n_rows_uploaded, dev.n_rows_expanded) == (uploaded, expanded)
+    scalars = mx.frame().scalars
+    assert scalars["store.upload_rows"] == uploaded
+    assert scalars["store.expanded_rows"] == expanded
+
+
+# ------------------------------------------------- compact upload
+
+class _PaddedBlockBatch(DeviceStoreBatch):
+    """The flush with the K/R padding assembled on the host: a fresh
+    zero-filled (K·R, v_max, C) block, each group's rows copied in by
+    fancy index, uploaded whole and scattered without an index. The
+    reference the compact upload must match bit for bit."""
+
+    def _flush_bucket(self, groups, R):
+        K = _pow2(len(groups))
+        groups = groups + [groups[0]] * (K - len(groups))
+        all_clients = (K == len(self.stores)
+                       and all(g[0] == i for i, g in enumerate(groups)))
+        rows = np.zeros((K * R, self.v_max, self.n_classes), np.float32)
+        rmask = np.zeros((K * R,), np.float32)
+        cu = np.zeros((K,), np.int32)
+        slots = np.zeros((K, R), np.int32)
+        for k, (c, blk) in enumerate(groups):
+            s = self.stores[c]
+            cu[k] = c
+            slots[k] = blk + [blk[-1]] * (R - len(blk))
+            rows[k * R:(k + 1) * R, :s.v_pad] = s.preds[slots[k]]
+            rmask[k * R:(k + 1) * R] = s.mask[slots[k]]
+        self.preds, self.pnorm, self.masks, self.acc, self.S = _flush(
+            self.preds, self.pnorm, self.masks, self.acc, self.S,
+            self.labels, self.nv, *map(jnp.asarray, (rows, rmask, cu, slots)),
+            all_clients=all_clients)
+        self.n_flushes += 1
+
+
+def _mixed_stores(seed):
+    """Four streaming stores, two of them twice as wide on the validation
+    axis (v_pad 256) as the others (128), so the batch pads V to 256."""
+    rng = np.random.default_rng(seed)
+    n_val = [96, 200, 96, 150]
+    return [StreamingPredictionStore(c, CAP, np.zeros((n, 2), np.float32),
+                                     rng.integers(0, C, n), C)
+            for c, n in enumerate(n_val)], rng
+
+
+def _add(stores, rng, c, gid, t=0.0):
+    s = stores[c]
+    p = rng.random((s.n_val, C)).astype(np.float32)
+    s.add(_entry(gid, owner=gid % len(stores)),
+          preds=p / p.sum(1, keepdims=True), t=t)
+
+
+def _state(dev):
+    return [np.asarray(a) for a in (dev.preds, dev.pnorm, dev.masks,
+                                    dev.acc, dev.S)]
+
+
+# each step: the (client, model id) adds before one flush of both batches
+_STEPS = {
+    # light dirt everywhere plus a full client: two buckets
+    "skewed_buckets": [[(c, 0) for c in range(N)]
+                       + [(2, g) for g in range(1, CAP)],
+                       [(0, 1), (1, 1), (1, 2), (3, 9), (3, 10)]],
+    # three dirty clients pad the group count to four (repeating group
+    # 0, which holds more than one row)
+    "k_padding": [[(c, g) for c in (0, 1, 3) for g in range(3)],
+                  [(0, 5), (0, 6), (2, 5), (3, 5)]],
+    # three rows pad the slot width to four, five to eight
+    "r_padding": [[(1, g) for g in range(3)], [(2, g) for g in range(5)]],
+    # every client dirty at one width: the group gather is elided
+    "all_clients": [[(c, g) for c in range(N) for g in range(2)],
+                    [(c, g) for c in range(N) for g in range(2, 4)]],
+    # a wide store's rows, then a narrow store's in the same block
+    "narrow_after_wide": [[(1, g) for g in range(4)],
+                          [(0, g) for g in range(4)],
+                          [(3, 20), (1, 21)], [(2, 20), (0, 21)]],
+    # more model ids than slots: evictions zero rows between flushes
+    "churn": [[(c, g) for c in range(N) for g in range(CAP)],
+              [(c, g) for c in (0, 1) for g in range(CAP, CAP + 3)],
+              [(3, g) for g in range(2 * CAP, 2 * CAP + 6)]],
+}
+
+
+@pytest.mark.parametrize("steps", list(_STEPS), ids=list(_STEPS))
+def test_compact_upload_matches_padded_block_bit_for_bit(steps):
+    """After every flush the compact upload leaves preds, pnorm, masks,
+    acc and S equal to those of the padded host block, bit for bit."""
+    stores, rng = _mixed_stores(seed=14)
+    dev, ref = DeviceStoreBatch(stores), _PaddedBlockBatch(stores)
+    assert dev.v_max == 256 and stores[0].v_pad == 128
+    for t, adds in enumerate(_STEPS[steps]):
+        for c, gid in adds:
+            _add(stores, rng, c, gid, t=float(t))
+        assert dev.flush() == ref.flush() > 0
+        for got, want in zip(_state(dev), _state(ref)):
+            np.testing.assert_array_equal(got, want)
+    assert dev.n_flushes == ref.n_flushes
+
+
+def _aligned_empty(shape):
+    """An uninitialised float32 array on a 4 KiB boundary: a CPU upload of
+    it aliases host memory instead of copying."""
+    n = int(np.prod(shape)) * 4
+    raw = np.empty(n + 4096, np.uint8)
+    off = (-raw.ctypes.data) % 4096
+    return raw[off:off + n].view(np.float32).reshape(shape)
+
+
+def test_staging_block_reuse_back_to_back_flushes(monkeypatch):
+    """Flushes issued back to back, with nothing waited on between them,
+    rewrite the same host staging block each time (a narrow store's rows
+    where a wide store's were), while the device reads each upload only
+    after a slow computation. The device must still end equal to a batch
+    flushed once from the final stores, with zero V tails."""
+    import jax
+
+    import repro.core.device_store as ds
+    x = jnp.ones((1024, 1024), jnp.float32)
+
+    @jax.jit
+    def late(rows, x):        # reads `rows` after a ~0.1 s product
+        return rows * (1.0 + 0.0 * jnp.sum(jnp.tanh(x @ x @ x)))
+
+    def slow_expand(rows, row_mask, src):
+        return _expand(late(rows, x), row_mask, src)
+
+    stores, rng = _mixed_stores(seed=15)
+    dev = DeviceStoreBatch(stores)
+    # blocks a CPU upload aliases: a rewrite under a pending read shows
+    for u in (4, 8, 16):
+        dev._stage[u] = _aligned_empty((u, dev.v_max, C))
+    blocks = dict(dev._stage)
+    monkeypatch.setattr(ds, "_expand", slow_expand)
+    for r in range(6):
+        for c in ((1, 3) if r % 2 == 0 else (0, 2)):    # wide, narrow
+            for g in range(4):
+                _add(stores, rng, c, 10 * r + g, t=float(r))
+        dev.flush()
+    monkeypatch.setattr(ds, "_expand", _expand)
+    assert all(dev._stage[u] is blocks[u] for u in blocks)
+    fresh = DeviceStoreBatch(stores)
+    fresh.flush()
+    for got, want in zip(_state(dev), _state(fresh)):
+        np.testing.assert_array_equal(got, want)
+    preds = np.asarray(dev.preds)
+    for c, s in enumerate(stores):
+        np.testing.assert_array_equal(preds[c, :, s.v_pad:], 0.0)
 
 
 def _spans_after_selects(metrics):
@@ -440,17 +618,21 @@ def test_spans_change_no_result_and_the_null_registry_keeps_none():
 def test_compiled_programs_carry_the_device_scopes():
     """The scopes a profiler trace is reduced by are in the optimized
     HLO's op names: a refactor that drops one fails here."""
-    from repro.core.device_store import _flush, _gather
+    from repro.core.device_store import _gather
     stores, rng = _fresh_stores(seed=2)
     _churn(stores, rng)
     dev = DeviceStoreBatch(stores)
-    K, R = 2, 4
+    K, R, U = 2, 4, 8
     rows = jnp.zeros((K * R, dev.v_max, C), jnp.float32)
     flush = _flush.lower(
         dev.preds, dev.pnorm, dev.masks, dev.acc, dev.S, dev.labels, dev.nv,
         rows, jnp.ones((K * R,), jnp.float32), jnp.arange(K, dtype=jnp.int32),
         jnp.zeros((K, R), jnp.int32)).compile().as_text()
     assert "/flush/scatter/" in flush and "/flush/gram/" in flush
+    expand = _expand.lower(rows[:U], jnp.ones((U,), jnp.float32),
+                           jnp.arange(K * R, dtype=jnp.int32) % U
+                           ).compile().as_text()
+    assert "/flush/scatter/" in expand
     idx = jnp.arange(2, dtype=jnp.int32)
     gather = _gather.lower(dev.preds, dev.labels, dev.masks, dev.acc, dev.S,
                            idx).compile().as_text()

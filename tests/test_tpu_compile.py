@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.device_store import _flush
+from repro.core.device_store import _expand, _flush
 from repro.kernels.ensemble_fitness.kernel import (ensemble_fitness,
                                                    ensemble_fitness_batched)
 
@@ -100,10 +100,13 @@ def test_multi_model_forward_compiles(one_chip, family):
 
 def test_flush_compiles_at_paper_cell(one_chip):
     """The donated dirty-row scatter + stats update over the resident
-    (20, 100, 768, 100) store, for two clients with two dirty slots."""
+    (20, 100, 768, 100) store, for two clients with two dirty slots, and
+    the expansion of a synchronous round's compact upload (2,000 rows in
+    a 2,048-row block, padded to 32 x 128)."""
     N, M, V, C, K, R = 20, 100, 768, 100, 2, 2
     f, i = (lambda *s: _spec(one_chip, *s)), \
         (lambda *s: _spec(one_chip, *s, dtype=jnp.int32))
+    _expand.lower(f(2048, V, C), f(2048), i(32 * 128)).compile()
     compiled = _flush.lower(
         f(N, M, V, C), f(N, M, V, C), f(N, M), f(N, M), f(N, M, M),
         i(N, V), f(N), f(K * R, V, C), f(K * R), i(K), i(K, R),
