@@ -54,19 +54,47 @@ opens host spans (`store.flush` and its drain/assemble/upload/launch
 children) and mirrors the four counts (`store.upload_bytes`,
 `store.useful_bytes`, `store.upload_rows`, `store.expanded_rows`); the
 jitted programs carry device scopes (`flush`, `flush/scatter`,
-`flush/gram`, `gather`) in their op names.
+`flush/gram`, `gather`) in their op names. `n_rows_evicted`
+(`store.evicted_rows`) counts the stores' evictions each flush carries
+to the device.
+
+Sharding (DESIGN.md §15): with `shards > 1` every buffer is split by
+client over a 1-D `clients` mesh of the first `shards` devices, the
+client axis padded to a multiple of the shard count; client `c` lives
+on shard `c // n_local`. A flush routes each dirty group to its owner
+(span `store.flush.route`, series `store.shard_rows`), pads the group
+count and the row block to one power of two across the shards, and runs
+`_expand` and `_flush` under `shard_map`: each shard receives its own
+rows only and scatters them into its own clients. With `shards == 1`
+the programs are the unsharded ones, unchanged.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.objectives import F32
 from repro.obs.metrics import NULL_METRICS
+
+
+CLIENTS = "clients"      # the mesh axis the fleet's client axis is split on
+
+
+def client_mesh(shards: int) -> Mesh:
+    """A 1-D `clients` mesh over the first `shards` devices."""
+    devices = jax.devices()
+    if shards > len(devices):
+        raise ValueError(
+            f"shards={shards} needs {shards} devices, but JAX sees "
+            f"{len(devices)} ({devices[0].platform}); lower "
+            "SelectionSpec.shards or run on a host with more chips")
+    return Mesh(np.array(devices[:shards]), (CLIENTS,))
 
 
 def _pow2(n: int) -> int:
@@ -74,6 +102,26 @@ def _pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def split_lanes(clients, n_local: int, shards: int):
+    """A select's GA lanes, shard-major: each shard's clients in order
+    (client c on shard c // n_local), padded to W = pow2 of the busiest
+    shard's count by repeating its first client (a shard with none
+    repeats its own first client). Returns (lanes (shards·W,) client
+    ids, the lane of each of `clients`). One shard: `clients` padded to
+    a power of two by repeating the first."""
+    per = [[] for _ in range(shards)]
+    for i, c in enumerate(clients):
+        per[c // n_local].append(i)
+    W = _pow2(max(len(p) for p in per))
+    lanes, pos = [], [0] * len(clients)
+    for sh, p in enumerate(per):
+        for i in p:
+            pos[i] = len(lanes)
+            lanes.append(clients[i])
+        lanes += [clients[p[0]] if p else sh * n_local] * (W - len(p))
+    return lanes, pos
 
 
 def _zero_row_acc(label_row: np.ndarray) -> np.float32:
@@ -158,43 +206,90 @@ def _gather(preds, labels, masks, acc, S, idx):
         return take(preds), take(labels), take(masks), take(acc), take(S)
 
 
-class DeviceStoreBatch:
-    """Device mirror of a fleet of `PredictionStore`s + cached (acc, S)."""
+@lru_cache(maxsize=None)
+def sharded_programs(mesh: Mesh):
+    """`_expand` and `_flush` as one program each over the `clients`
+    mesh: every argument and result is split by its leading axis, and
+    each shard runs the unsharded function on its own block."""
+    spec = P(CLIENTS)
 
-    def __init__(self, stores, v_max: Optional[int] = None):
+    def over(fn, n_in, n_out):
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in,
+                             out_specs=(spec,) * n_out, check_vma=False)
+
+    def flush(*args, all_clients: bool = False):
+        return over(partial(_flush, all_clients=all_clients), 11, 5)(*args)
+
+    return (jax.jit(over(_expand, 3, 2)),
+            jax.jit(flush, static_argnames=("all_clients",),
+                    donate_argnums=(0, 1, 2, 3, 4)))
+
+
+class DeviceStoreBatch:
+    """Device mirror of a fleet of `PredictionStore`s + cached (acc, S).
+
+    `shards > 1` splits every buffer by client over `client_mesh(shards)`
+    (module docstring); `shards == 1` keeps them on the default device."""
+
+    def __init__(self, stores, v_max: Optional[int] = None,
+                 shards: int = 1):
         stores = list(stores)
         assert stores, "DeviceStoreBatch needs at least one store"
         cap = stores[0].capacity
         C = stores[0].n_classes
         self.v_max = max(s.v_pad for s in stores) if v_max is None else v_max
         self.capacity, self.n_classes = cap, C
+        if shards < 1:
+            raise ValueError(f"shards={shards}: a batch needs one or more")
+        self.shards = shards
+        self.n_local = -(-len(stores) // shards)   # clients per shard
+        n_pad = self.n_local * shards
+        self.mesh = client_mesh(shards) if shards > 1 else None
         self.stores: List = []
         self._dirty: List[set] = []        # per-client pending slot events
         self._cursor: List[int] = []       # per-client dirty-log position
+        self._evicted: List[int] = []      # per-client eviction count seen
         self.n_flushes = 0
         self.n_rows_scattered = 0          # perf counters (bench/DESIGN §7)
         self.n_rows_uploaded = 0           # rows of the uploaded blocks
         self.n_rows_expanded = 0           # ... after the K/R padding
         self.n_bytes_uploaded = 0          # host->device bytes of flushes
         self.n_bytes_useful = 0            # ... that are real dirty rows
+        self.n_rows_evicted = 0            # evictions the flushes carried
         self.metrics = NULL_METRICS        # the engine's registry (§11)
         self._stage = {}                   # U -> host upload block
-        labels = np.full((len(stores), self.v_max), -1, np.int32)
-        self.preds = jnp.zeros((len(stores), cap, self.v_max, C), jnp.float32)
-        self.pnorm = jnp.zeros_like(self.preds)  # cached normalized mirror
-        self.masks = jnp.zeros((len(stores), cap), jnp.float32)
-        self.S = jnp.zeros((len(stores), cap, cap), jnp.float32)
+        labels = np.full((n_pad, self.v_max), -1, np.int32)
+        self.preds = self._zeros((n_pad, cap, self.v_max, C))
+        self.pnorm = self._zeros(self.preds.shape)  # normalized mirror
+        self.masks = self._zeros((n_pad, cap))
+        self.S = self._zeros((n_pad, cap, cap))
         for i, s in enumerate(stores):
             self._attach(s, labels[i])
-        self.labels = jnp.asarray(labels)
+        self.labels = self._put(labels)
         # valid samples per client on the host (the useful-bytes count),
         # and in fp32 on device, the shared denominator of acc and S
         self._n_valid = (labels >= 0).sum(1)
-        self.nv = jnp.asarray(np.maximum(self._n_valid, 1)
-                              .astype(np.float32))
+        self.nv = self._put(np.maximum(self._n_valid, 1)
+                            .astype(np.float32))
         acc0 = np.stack([np.full((cap,), _zero_row_acc(labels[i]), np.float32)
-                         for i in range(len(stores))])
-        self.acc = jnp.asarray(acc0)
+                         for i in range(n_pad)])
+        self.acc = self._put(acc0)
+
+    # ---- placement ----------------------------------------------------
+    def _put(self, a: np.ndarray):
+        """A host array on the batch's devices: split by its leading
+        (client) axis over the mesh when sharded."""
+        if self.mesh is None:
+            return jnp.asarray(a)
+        return jax.device_put(a, NamedSharding(self.mesh, P(CLIENTS)))
+
+    def _zeros(self, shape):
+        """A float32 zero buffer, made in place on the batch's devices
+        (a sharded one never exists whole on one device)."""
+        if self.mesh is None:
+            return jnp.zeros(shape, jnp.float32)
+        return jax.jit(lambda: jnp.zeros(shape, jnp.float32),
+                       out_shardings=NamedSharding(self.mesh, P(CLIENTS)))()
 
     # ---- membership ---------------------------------------------------
     def _attach(self, store, label_row: np.ndarray):
@@ -214,11 +309,19 @@ class DeviceStoreBatch:
         self._dirty.append(set(np.flatnonzero(store.mask))
                            | set(store.dirty_seq))
         self._cursor.append(store._dirty_clock)
+        self._evicted.append(store.evictions)
 
     def append_store(self, store):
         """Grow the fleet by one client (churn join). The device buffers
         are reallocated with one extra row; the newcomer's slots flush on
-        the next `flush()`."""
+        the next `flush()`. A sharded batch's client axis is fixed."""
+        if self.mesh is not None:
+            raise NotImplementedError(
+                f"append_store on a batch sharded over {self.shards} "
+                "devices: its client axis is fixed at build time (padded "
+                "to a multiple of the shard count); build the engine with "
+                "every client, or with shards=1")
+        self.n_local += 1
         labels = np.asarray(self.labels)
         row = np.full((1, self.v_max), -1, np.int32)
         self._attach(store, row[0])
@@ -246,20 +349,21 @@ class DeviceStoreBatch:
         row = np.full((self.v_max,), -1, np.int32)
         row[:store.v_pad] = store.labels
         labels[client] = row
-        self.labels = jnp.asarray(labels)
+        self.labels = self._put(labels)
         self._n_valid[client] = int((row >= 0).sum())
         nv = np.array(self.nv)
         nv[client] = max(int(self._n_valid[client]), 1)
-        self.nv = jnp.asarray(nv)
+        self.nv = self._put(nv)
         acc = np.array(self.acc)
         acc[client] = _zero_row_acc(row)
-        self.acc = jnp.asarray(acc)
+        self.acc = self._put(acc)
         self._dirty[client].update(range(self.capacity))
 
     # ---- incremental flush --------------------------------------------
     def _drain(self):
         """Per-client sorted dirty-slot groups (advancing OUR cursor over
-        each store's dirty log — multi-consumer safe).
+        each store's dirty log — multi-consumer safe), counting the
+        evictions they carry into `n_rows_evicted`.
         Returns (groups [(client, slots)], n_distinct_dirty_slots)."""
         groups, n_dirty = [], 0
         for i, s in enumerate(self.stores):
@@ -268,6 +372,8 @@ class DeviceStoreBatch:
                     slot for slot, seq in s.dirty_seq.items()
                     if seq > self._cursor[i])
                 self._cursor[i] = s._dirty_clock
+                self.n_rows_evicted += s.evictions - self._evicted[i]
+                self._evicted[i] = s.evictions
             slots = sorted(self._dirty[i])
             self._dirty[i].clear()
             if slots:
@@ -293,50 +399,77 @@ class DeviceStoreBatch:
         """One donated scatter+recompute for all groups padded to width R
         (`groups` are distinct clients: the useful bytes are theirs).
         Each distinct dirty row is uploaded once, in a block of U =
-        pow2(rows) rows, and `_expand` pads it on the device."""
+        pow2(rows) rows, and `_expand` pads it on the device. Sharded,
+        each shard gets a block of its own rows, K and U are the largest
+        over the shards, and a shard with nothing dirty pads with a
+        client index past its last, whose scatters are dropped."""
         mx = self.metrics
         useful = sum(len(blk) * int(self._n_valid[c])
                      for c, blk in groups) * self.n_classes * 4
+        # the groups of each shard as (local client, client, slots);
+        # spans and series only where there is more than one shard
+        rmx = mx if self.mesh is not None else NULL_METRICS
+        with rmx.span("store.flush.route"):
+            per = [[] for _ in range(self.shards)]
+            for c, blk in groups:
+                per[c // self.n_local].append((c % self.n_local, c, blk))
+            if rmx.enabled:
+                for sh, gs in enumerate(per):
+                    rmx.observe("store.shard_rows",
+                                sum(len(blk) for *_, blk in gs), shard=sh)
         with mx.span("store.flush.assemble"):
-            G, K = len(groups), _pow2(len(groups))
-            all_clients = (K == G == len(self.stores)
-                           and all(g[0] == i for i, g in enumerate(groups)))
-            U = _pow2(sum(len(blk) for _, blk in groups))
-            rows = self._staging(U)
-            rmask = np.zeros((U,), np.float32)
-            cu = np.zeros((K,), np.int32)
-            slots = np.zeros((K, R), np.int32)
-            src = np.zeros((K, R), np.int32)
-            i = 0
-            for k, (c, blk) in enumerate(groups):
-                s, n = self.stores[c], len(blk)
-                cu[k] = c
-                slots[k] = blk + [blk[-1]] * (R - n)
-                src[k] = i + np.minimum(np.arange(R), n - 1)
-                for j, slot in enumerate(blk):
-                    rows[i + j, :s.v_pad] = s.preds[slot]
-                rows[i:i + n, s.v_pad:] = 0.0
-                rmask[i:i + n] = s.mask[blk]
-                i += n
-            # the group count is padded by repeating group 0
-            cu[G:], slots[G:], src[G:] = cu[0], slots[0], src[0]
+            n_sh, n_loc = self.shards, self.n_local
+            K = _pow2(max(len(gs) for gs in per))
+            U = _pow2(max(max(1, sum(len(blk) for *_, blk in gs))
+                          for gs in per))
+            all_clients = all(
+                len(gs) == K == n_loc
+                and all(g[0] == i for i, g in enumerate(gs)) for gs in per)
+            rows = self._staging(n_sh * U)
+            rmask = np.zeros((n_sh * U,), np.float32)
+            cu = np.zeros((n_sh * K,), np.int32)
+            slots = np.zeros((n_sh * K, R), np.int32)
+            src = np.zeros((n_sh * K, R), np.int32)
+            for sh, gs in enumerate(per):
+                g0, i = sh * K, 0
+                for k, (lc, c, blk) in enumerate(gs):
+                    s, n, r = self.stores[c], len(blk), sh * U + i
+                    cu[g0 + k] = lc
+                    slots[g0 + k] = blk + [blk[-1]] * (R - n)
+                    src[g0 + k] = i + np.minimum(np.arange(R), n - 1)
+                    for j, slot in enumerate(blk):
+                        rows[r + j, :s.v_pad] = s.preds[slot]
+                    rows[r:r + n, s.v_pad:] = 0.0
+                    rmask[r:r + n] = s.mask[blk]
+                    i += n
+                if gs:
+                    # the group count is padded by repeating group 0
+                    G = len(gs)
+                    cu[g0 + G:g0 + K] = cu[g0]
+                    slots[g0 + G:g0 + K] = slots[g0]
+                    src[g0 + G:g0 + K] = src[g0]
+                else:
+                    cu[g0:g0 + K] = n_loc
+                    rows[sh * U] = 0.0
         host = (rows, rmask, src.reshape(-1), cu, slots)
         with mx.span("store.flush.upload"):
-            dev = [jnp.asarray(a) for a in host]
+            dev = [self._put(a) for a in host]
         with mx.span("store.flush.launch"):
-            self.preds, self.pnorm, self.masks, self.acc, self.S = _flush(
+            expand, flush = ((_expand, _flush) if self.mesh is None
+                             else sharded_programs(self.mesh))
+            self.preds, self.pnorm, self.masks, self.acc, self.S = flush(
                 self.preds, self.pnorm, self.masks, self.acc, self.S,
-                self.labels, self.nv, *_expand(*dev[:3]), *dev[3:],
+                self.labels, self.nv, *expand(*dev[:3]), *dev[3:],
                 all_clients=all_clients)
         uploaded = sum(a.nbytes for a in host)
         self.n_flushes += 1
-        self.n_rows_uploaded += U
-        self.n_rows_expanded += K * R
+        self.n_rows_uploaded += n_sh * U
+        self.n_rows_expanded += n_sh * K * R
         self.n_bytes_uploaded += uploaded
         self.n_bytes_useful += useful
         if mx.enabled:
-            mx.inc("store.upload_rows", U)
-            mx.inc("store.expanded_rows", K * R)
+            mx.inc("store.upload_rows", n_sh * U)
+            mx.inc("store.expanded_rows", n_sh * K * R)
             mx.inc("store.upload_bytes", uploaded)
             mx.inc("store.useful_bytes", useful)
 
@@ -355,7 +488,11 @@ class DeviceStoreBatch:
         mx = self.metrics
         with mx.span("store.flush"):
             with mx.span("store.flush.drain"):
+                evicted = self.n_rows_evicted
                 groups, n_dirty = self._drain()
+                if mx.enabled and self.n_rows_evicted > evicted:
+                    mx.inc("store.evicted_rows",
+                           self.n_rows_evicted - evicted)
             if not groups:
                 return 0
             buckets = {}
@@ -373,7 +510,9 @@ class DeviceStoreBatch:
     # ---- batched reads ------------------------------------------------
     def gather(self, clients):
         """(preds, labels, masks, acc, S) for a client batch — a device
-        `jnp.take` per buffer, no host restack. Call `flush()` first."""
+        `jnp.take` per buffer, no host restack. Call `flush()` first.
+        Unsharded batches only: a sharded select gathers on each shard,
+        inside the engine's sharded program."""
         idx = jnp.asarray(np.asarray(clients, np.int32))
         return _gather(self.preds, self.labels, self.masks,
                        self.acc, self.S, idx)

@@ -21,21 +21,51 @@ Client batches are padded to the next power of two (by repeating the
 first client) so the jitted program is compiled for O(log N) distinct
 batch sizes no matter how the async event stream groups re-selections
 (DESIGN.md §4).
+
+With `shards > 1` the device batch is split by client over that many
+devices (DESIGN.md §15): a select splits its clients by owner shard
+(span `engine.select.split`), pads every shard to one power-of-two
+width W (series `engine.select.shard_width`), and runs the gather, the
+GA and the vote as ONE program over the shards, each on its own
+clients; the results are fetched once. PRNG streams stay keyed by
+client id, so an answer does not depend on the shard count. Every
+select counts its GA lanes and the padded ones (`n_lanes`,
+`n_pad_lanes`; counters `engine.select.lanes`, `engine.select.pad_lanes`).
 """
 from __future__ import annotations
 
+from functools import lru_cache
 from typing import Dict, Iterable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.bench import stack_stores
-from repro.core.device_store import DeviceStoreBatch
-from repro.core.device_store import _pow2 as _pow2_pad
+from repro.core.device_store import (CLIENTS, DeviceStoreBatch, _gather,
+                                     split_lanes)
 from repro.core.nsga2 import NSGAConfig, client_keys
 from repro.core.selection import (local_only_chromosome, select_ensembles,
                                   select_ensembles_from_stats)
 from repro.obs.metrics import NULL_METRICS
+
+
+@lru_cache(maxsize=None)
+def _sharded_select(mesh, nsga: NSGAConfig, use_kernel: bool):
+    """The gather and `select_ensembles_from_stats` as one program over
+    the `clients` mesh: each shard selects for its own lanes, from its
+    own resident buffers (`idx`: local client ids)."""
+    def per_shard(preds, labels, masks, acc, S, idx, keys):
+        preds, labels, masks, acc, S = _gather(preds, labels, masks, acc,
+                                               S, idx)
+        return select_ensembles_from_stats(
+            acc, S, preds, labels, nsga, use_kernel=use_kernel, keys=keys,
+            model_mask=masks)
+    return jax.jit(jax.shard_map(per_shard, mesh=mesh,
+                                 in_specs=(P(CLIENTS),) * 7,
+                                 out_specs=P(CLIENTS), check_vma=False))
 
 
 class SelectionEngine:
@@ -44,7 +74,7 @@ class SelectionEngine:
     def __init__(self, stores, nsga: NSGAConfig, use_kernel: bool = False,
                  seed: int = 0, ensemble_k: Optional[int] = None,
                  device_resident: bool = True, v_max: Optional[int] = None,
-                 metrics=None):
+                 metrics=None, shards: int = 1):
         self.stores = list(stores)
         self.nsga = nsga
         self.use_kernel = use_kernel
@@ -61,12 +91,19 @@ class SelectionEngine:
                 f"engine v_max={v_max} narrower than an attached store's "
                 f"v_pad={widest}")
         self._v_max = widest if v_max is None else v_max
-        self.device = (DeviceStoreBatch(self.stores, v_max=self._v_max)
+        if shards > 1 and not device_resident:
+            raise ValueError("shards > 1 splits the device-resident batch; "
+                             "it needs device_resident=True")
+        self.shards = shards
+        self.device = (DeviceStoreBatch(self.stores, v_max=self._v_max,
+                                        shards=shards)
                        if device_resident else None)
         self.metrics = metrics if metrics is not None else NULL_METRICS
         self.results: Dict[int, dict] = {}   # client -> last selection dict
         self._keys_cache: Dict[tuple, object] = {}  # batch -> PRNG streams
         self._n_selects = 0                  # the `select_id` of spans
+        self.n_lanes = 0                     # GA lanes launched ...
+        self.n_pad_lanes = 0                 # ... of which padding
 
     @property
     def metrics(self):
@@ -95,9 +132,9 @@ class SelectionEngine:
         engine-wide pad and mirror it into the device batch. Returns the
         new client index."""
         self._check_width(store)
-        self.stores.append(store)
         if self.device is not None:
             self.device.append_store(store)
+        self.stores.append(store)
         return len(self.stores) - 1
 
     # ---- selection ----------------------------------------------------
@@ -122,13 +159,24 @@ class SelectionEngine:
             return {}
         for c in ready:
             self._check_width(self.stores[c])
-        B = _pow2_pad(len(ready))
         mx = self.metrics
-        if mx.enabled:
-            mx.observe("engine.ga_batch_width", B, t=t)
-        batch = ready + [ready[0]] * (B - len(ready))
         self._n_selects += 1
         with mx.span("engine.select", select_id=self._n_selects):
+            # spans and series of the split only where there are shards
+            smx = mx if self.shards > 1 else NULL_METRICS
+            with smx.span("engine.select.split"):
+                n_local = (len(self.stores) if self.device is None
+                           else self.device.n_local)
+                batch, lanes = split_lanes(ready, n_local, self.shards)
+            if smx.enabled:
+                smx.observe("engine.select.shard_width",
+                            len(batch) // self.shards, t=t)
+            self.n_lanes += len(batch)
+            self.n_pad_lanes += len(batch) - len(ready)
+            if mx.enabled:
+                mx.observe("engine.ga_batch_width", len(batch), t=t)
+                mx.inc("engine.select.lanes", len(batch))
+                mx.inc("engine.select.pad_lanes", len(batch) - len(ready))
             with mx.span("engine.select.keys"):
                 keys = self._batch_keys(batch)
             out = self._launch(batch, keys, t)
@@ -139,7 +187,7 @@ class SelectionEngine:
                 host = {k: np.asarray(v) for k, v in out.items()}
             with mx.span("engine.select.unpack"):
                 fresh = {}
-                for i, c in enumerate(ready):
+                for i, c in zip(lanes, ready):
                     res = {k: v[i] for k, v in host.items()}
                     res["slot_gen"] = self.stores[c].slot_gen.copy()
                     self.stores[c].note_selection(res["chromosome"] > 0.5, t)
@@ -148,12 +196,16 @@ class SelectionEngine:
         return fresh
 
     def _batch_keys(self, batch):
-        """The batch's per-client PRNG streams, cached by composition."""
+        """The batch's per-client PRNG streams, cached by composition
+        (split over the shards with the lanes when sharded)."""
         keys = self._keys_cache.get(tuple(batch))
         if keys is None:
             if len(self._keys_cache) >= 128:   # churn can produce a new
                 self._keys_cache.clear()       # composition per tick —
             keys = client_keys(self.seed, np.asarray(batch, np.uint32))
+            if self.shards > 1:
+                keys = jax.device_put(keys, NamedSharding(
+                    self.device.mesh, P(CLIENTS)))
             self._keys_cache[tuple(batch)] = keys  # keep the cache bounded
         return keys
 
@@ -176,13 +228,21 @@ class SelectionEngine:
         # whole-fleet batch in natural order is served from the resident
         # buffers directly (identity gather elided)
         dev = self.device
-        if dev.preds.shape[0] != len(self.stores):
+        if len(dev.stores) != len(self.stores):
             raise RuntimeError(
                 "engine.stores grew without the device mirror — "
                 "admit late joiners through engine.add_store()")
         n_dirty = dev.flush()
         if mx.enabled:
             mx.observe("engine.flush_dirty_slots", n_dirty, t=t)
+        if self.shards > 1:
+            with mx.span("engine.select.gather"):
+                idx = dev._put(np.asarray(batch, np.int32) % dev.n_local)
+            with mx.span("engine.select.launch"):
+                return _sharded_select(dev.mesh, self.nsga,
+                                       self.use_kernel)(
+                    dev.preds, dev.labels, dev.masks, dev.acc, dev.S, idx,
+                    keys)
         with mx.span("engine.select.gather"):
             if batch == list(range(len(self.stores))):
                 preds, labels, masks, acc, S = (dev.preds, dev.labels,

@@ -102,18 +102,31 @@ def _tournament(key, ranks, crowd, n):
     return jnp.where(a_better, a, b)
 
 
+def keep_top_k(score, k: int):
+    """(P, M) float 0/1 marking the k largest scores of each row. Scores
+    equal to the k-th largest are kept leftmost first, so a tie there
+    never gives a row more than k ones."""
+    thresh = -jnp.sort(-score, axis=1)[:, k - 1:k]  # k-th largest
+    above = score > thresh
+    tied = score == thresh
+    room = k - above.sum(axis=1, keepdims=True)
+    return (above | (tied & (jnp.cumsum(tied, axis=1) <= room))
+            ).astype(jnp.float32)
+
+
 def repair_k(pop_f, key, k: int, valid_mask=None):
     """Force exactly k ones per row: keep set bits with priority, fill the
     rest randomly. pop_f: (P, M) float 0/1. With `valid_mask` (M,) 0/1,
     masked-out slots score below every valid slot and can never be set —
-    rows end up with min(k, #valid) ones."""
+    rows end up with min(k, #valid) ones. The float32 noise ties about
+    once in a million rows (2 + noise has 2^22 values), hence
+    `keep_top_k`'s tie rule."""
     P, M = pop_f.shape
     noise = jax.random.uniform(key, (P, M))
     score = pop_f * 2.0 + noise  # existing bits rank above absent ones
     if valid_mask is not None:
         score = score - (1.0 - valid_mask) * 8.0
-    thresh = -jnp.sort(-score, axis=1)[:, k - 1:k]  # k-th largest
-    rep = (score >= thresh).astype(jnp.float32)
+    rep = keep_top_k(score, k)
     if valid_mask is not None:
         rep = rep * valid_mask
     return rep

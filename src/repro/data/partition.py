@@ -1,6 +1,8 @@
 """Non-IID client partitioning: Dirichlet label skew (Hsu et al. 2019),
 exactly the paper's protocol: per-class proportions ~ Dir(alpha) across
-clients; 70/15/15 train/val/test split per client."""
+clients; 70/15/15 train/val/test split per client. And LEAF's natural
+partition of FEMNIST, one writer per client (Caldas et al. 2018), at
+the published writer sizes."""
 from __future__ import annotations
 
 import numpy as np
@@ -26,6 +28,27 @@ def dirichlet_partition(labels: np.ndarray, n_clients: int, alpha: float,
         seed += 1
         rng = np.random.default_rng(seed)
     return [np.array(sorted(ix), dtype=np.int64) for ix in idx_by_client]
+
+
+# LEAF FEMNIST (Caldas et al., arXiv:1812.01097, Table 1): 3,550 writers,
+# 62 classes, samples per writer mean and standard deviation
+FEMNIST_WRITERS = 3550
+FEMNIST_SAMPLES_PER_WRITER = (226.83, 88.94)
+
+
+def writers_partition(n_writers: int, n_classes: int, mean: float,
+                      sd: float, seed: int = 0, min_size: int = 12):
+    """One writer per client: writer sizes ~ N(mean, sd), rounded and
+    held at `min_size` or more, labels uniform over the classes (LEAF
+    publishes no per-class counts; FEMNIST's own classes are imbalanced,
+    digits far more frequent than letters). Returns (labels, one
+    contiguous index array per writer)."""
+    rng = np.random.default_rng(seed)
+    sizes = np.maximum(np.rint(rng.normal(mean, sd, n_writers)),
+                       min_size).astype(np.int64)
+    labels = rng.integers(0, n_classes, int(sizes.sum())).astype(np.int32)
+    ends = np.cumsum(sizes)
+    return labels, [np.arange(e - n, e) for n, e in zip(sizes, ends)]
 
 
 def split_train_val_test(idx: np.ndarray, seed: int = 0,

@@ -347,14 +347,25 @@ def build_prediction_world(data: DataSpec, default_seed: int
     parameterized prediction matrices — local models better than remote
     on average, no CNN training needed. Returns (labels, mats) with
     labels[c] = (V,) int labels and mats[(c, global_model_id)] = (V, C)
-    row-normalized probabilities."""
+    row-normalized probabilities. V is `n_val`, or with
+    partition="writers" each writer's validation split."""
     n, mpc = data.n_clients, data.models_per_client
     V, C = data.n_val, data.n_classes
     seed = data.seed if data.seed is not None else default_seed
     rng = np.random.default_rng(seed)
-    labels = {c: rng.integers(0, C, V) for c in range(n)}
+    if data.partition == "writers":
+        from repro.data import split_train_val_test, writers_partition
+        split_seed = data.split_seed if data.split_seed is not None \
+            else seed + 1
+        y, parts = writers_partition(n, C, *data.samples_per_writer,
+                                     seed=seed)
+        labels = {c: y[split_train_val_test(ix, seed=split_seed)[1]]
+                  for c, ix in enumerate(parts)}
+    else:
+        labels = {c: rng.integers(0, C, V) for c in range(n)}
     mats = {}
     for c in range(n):
+        V = len(labels[c])
         for owner in range(n):
             for m in range(mpc):
                 q = rng.uniform(*data.quality_local) if owner == c \
@@ -375,12 +386,12 @@ def build_world_stores(data: DataSpec, labels: dict,
     capacity is below the global model count (mirrors
     core.fedpae._empty_stores for the trainingless world)."""
     from repro.core.bench import PredictionStore, StreamingPredictionStore
-    n, mpc = data.n_clients, data.models_per_client
-    V, C = data.n_val, data.n_classes
+    n, mpc, C = data.n_clients, data.models_per_client, data.n_classes
     full = n * mpc
     cap = full if store_capacity is None else store_capacity
+    x_val = [np.zeros((len(labels[c]), 2), np.float32) for c in range(n)]
     if cap >= full:  # slot-aligned unbounded store, one slot per model
-        return [PredictionStore(c, full, np.zeros((V, 2), np.float32),
-                                labels[c], C) for c in range(n)]
-    return [StreamingPredictionStore(c, cap, np.zeros((V, 2), np.float32),
-                                     labels[c], C) for c in range(n)]
+        return [PredictionStore(c, full, x_val[c], labels[c], C)
+                for c in range(n)]
+    return [StreamingPredictionStore(c, cap, x_val[c], labels[c], C)
+            for c in range(n)]

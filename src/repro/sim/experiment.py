@@ -272,7 +272,7 @@ class Experiment:
                             else sel.k),
                 device_resident=sel.device_resident,
                 metrics=self.obs.metrics if self.obs is not None
-                else None)
+                else None, shards=sel.shards)
         if not sync:
             n_val = (max(len(d.y_va) for d in self.datasets)
                      if self.datasets else None)

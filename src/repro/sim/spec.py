@@ -12,7 +12,7 @@ scenario. Five sections mirror the five things a run needs:
                    ("external", the compatibility-shim path).
   TrainSpec      — local training: model families, lr, epochs, width.
   SelectionSpec  — NSGA-II shape, ensemble size, kernel/device-resident
-                   switches, bounded store capacity.
+                   switches, bounded store capacity, shard count.
   NetworkSpec    — topology plus four TAGGED component slots (transport,
                    gossip, churn, repair), each a `ComponentSpec` resolved
                    by name through `repro.sim.registry` so new transports
@@ -50,6 +50,7 @@ import json
 from typing import ClassVar, Optional, Tuple
 
 from repro.core.nsga2 import NSGAConfig
+from repro.data.partition import FEMNIST_SAMPLES_PER_WRITER
 
 
 def _check_keys(cls, d: dict, path: str) -> None:
@@ -119,13 +120,28 @@ class DataSpec:
     quality_remote: tuple = (0.2, 0.85)   # ... of peers' models
     seed: Optional[int] = None            # None -> ExperimentSpec.seed
     split_seed: Optional[int] = None      # None -> data seed + 1
+    # prediction_world: None gives every client n_val samples; "writers"
+    # is LEAF's one-writer-per-client partition (data.partition), each
+    # client validating on 15% of a writer of N(mean, sd) samples
+    partition: Optional[str] = None
+    samples_per_writer: tuple = FEMNIST_SAMPLES_PER_WRITER
+
+    PARTITIONS: ClassVar[Tuple[Optional[str], ...]] = (None, "writers")
 
     def __post_init__(self):
         if self.kind not in self.KINDS:
             raise ValueError(f"unknown data kind {self.kind!r}; "
                              f"choose from {self.KINDS}")
+        if self.partition not in self.PARTITIONS:
+            raise ValueError(f"unknown data partition {self.partition!r}; "
+                             f"choose from {self.PARTITIONS}")
+        if self.partition == "writers" and self.kind != "prediction_world":
+            raise ValueError('data.partition="writers" sizes the validation '
+                             'sets of a "prediction_world"; got data.kind='
+                             f"{self.kind!r}")
         self.quality_local = tuple(self.quality_local)
         self.quality_remote = tuple(self.quality_remote)
+        self.samples_per_writer = tuple(self.samples_per_writer)
 
 
 @dataclasses.dataclass
@@ -153,6 +169,8 @@ class SelectionSpec:
     use_kernel: bool = False
     device_resident: bool = True
     store_capacity: Optional[int] = None  # bounded streaming stores (§6)
+    shards: int = 1                       # devices the client axis of the
+                                          # device batch is split over (§15)
     seed: Optional[int] = None            # None -> ExperimentSpec.seed
 
     def nsga(self, default_seed: int) -> NSGAConfig:

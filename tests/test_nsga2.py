@@ -8,7 +8,8 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core.nsga2 import (NSGAConfig, crowding_distance, dominance,
-                              nondominated_rank, repair_k, run_nsga2)
+                              keep_top_k, nondominated_rank, repair_k,
+                              run_nsga2)
 from repro.core.objectives import (ensemble_accuracy, member_accuracy,
                                    population_objectives, similarity_matrix)
 from repro.core.selection import select_ensemble
@@ -40,6 +41,19 @@ def test_repair_k_exact(k, M, seed):
     both = np.asarray(jnp.sum(rep * pop, axis=1))
     orig = np.asarray(jnp.sum(pop, axis=1))
     assert (both >= np.minimum(orig, k) - 1e-6).all()
+
+
+@pytest.mark.parametrize("row,k,want", [
+    ([3.0, 2.0, 2.0, 2.0, 1.0], 2, [1, 1, 0, 0, 0]),
+    ([2.5, 2.5, 2.5, 0.1, 0.2], 2, [1, 1, 0, 0, 0]),
+    ([0.1, 2.5, 0.3, 2.5, 2.5], 1, [0, 1, 0, 0, 0]),
+    ([0.4, 0.9, 0.2, 0.7, 0.3], 3, [1, 1, 0, 1, 0]),
+])
+def test_keep_top_k_gives_k_ones_when_scores_tie(row, k, want):
+    """A tie at the k-th largest score keeps the leftmost (the exact-k
+    repair's float32 noise ties about once in a million rows)."""
+    got = np.asarray(keep_top_k(jnp.asarray([row], jnp.float32), k))
+    np.testing.assert_array_equal(got, [want])
 
 
 def test_crowding_boundary_is_infinite():

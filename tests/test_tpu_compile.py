@@ -8,16 +8,21 @@ because `ops` would pick the interpreter on a CPU host. Shapes are the
 paper cell's: N=20 clients, M=100 models, P=100 (survival pool 2P=200),
 V=656 validation rows padded to 768, C=100 classes, 32x32x3 images.
 
+The cross-device cell's programs compile per shard, at one shard of
+femnist_x4_async: 888 clients, M=128, V=128 (the store's pad), C=62,
+64 lanes.
+
 The topology is described inside a fixture, never at import: only one
 process may load libtpu at a time, and every test worker imports this
 file.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.device_store import _expand, _flush
+from repro.core.device_store import _expand, _flush, sharded_programs
 from repro.kernels.ensemble_fitness.kernel import (ensemble_fitness,
                                                    ensemble_fitness_batched)
 
@@ -112,5 +117,38 @@ def test_flush_compiles_at_paper_cell(one_chip):
         i(N, V), f(N), f(K * R, V, C), f(K * R), i(K), i(K, R),
         all_clients=False).compile()
     mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+
+
+def test_femnist_shard_programs_compile_and_fit(topo):
+    """One shard of femnist_x4_async (888 of 3,552 clients, 128-slot
+    stores, V=128, C=62) on a one-chip `clients` mesh: the window's
+    flush (64 clients, R=2) and its expansion, and the engine's sharded
+    select (gather, GA, vote) at 64 lanes. The resident buffers plus
+    each program's temporaries fit one chip."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core.device_store import CLIENTS
+    from repro.core.engine import _sharded_select
+    from repro.core.nsga2 import NSGAConfig
+    mesh = Mesh(np.array(topo.devices[:1]), (CLIENTS,))
+    shard = NamedSharding(mesh, PartitionSpec(CLIENTS))
+    n, M, V, C, K, R, B = 888, 128, 128, 62, 64, 2, 64
+    f, i = (lambda *s: _spec(shard, *s)), \
+        (lambda *s: _spec(shard, *s, dtype=jnp.int32))
+    expand, flush = sharded_programs(mesh)
+    expand.lower(f(K, V, C), f(K), i(K * R)).compile()
+    resident = (f(n, M, V, C), f(n, M, V, C), f(n, M), f(n, M),
+                f(n, M, M), i(n, V), f(n))
+    mem = flush.lower(*resident, f(K * R, V, C), f(K * R), i(K), i(K, R),
+                      all_clients=False).compile().memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    select = _sharded_select(mesh, NSGAConfig(pop_size=100,
+                                              generations=100, k=5), False)
+    mem = select.lower(resident[0], resident[5], *resident[2:5], i(B),
+                       _spec(shard, B, 2, dtype=jnp.uint32)
+                       ).compile().memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
