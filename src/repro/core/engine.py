@@ -30,7 +30,11 @@ GA and the vote as ONE program over the shards, each on its own
 clients; the results are fetched once. PRNG streams stay keyed by
 client id, so an answer does not depend on the shard count. Every
 select counts its GA lanes and the padded ones (`n_lanes`,
-`n_pad_lanes`; counters `engine.select.lanes`, `engine.select.pad_lanes`).
+`n_pad_lanes`; counters `engine.select.lanes`, `engine.select.pad_lanes`),
+and the front-peeling sweeps of its NSGA-II, the most over its real
+lanes (`n_rank_sweeps`; counter `engine.select.rank_sweeps`): a lower
+bound on the sweeps the lockstep loop ran, which shows how often the
+truncated sort stops early. No per-client result carries it.
 """
 from __future__ import annotations
 
@@ -104,6 +108,7 @@ class SelectionEngine:
         self._n_selects = 0                  # the `select_id` of spans
         self.n_lanes = 0                     # GA lanes launched ...
         self.n_pad_lanes = 0                 # ... of which padding
+        self.n_rank_sweeps = 0               # peeling sweeps, lane max
 
     @property
     def metrics(self):
@@ -185,6 +190,10 @@ class SelectionEngine:
             # transfers)
             with mx.span("engine.select.fetch"):
                 host = {k: np.asarray(v) for k, v in out.items()}
+            sweeps = int(host.pop("rank_sweeps")[lanes].max())
+            self.n_rank_sweeps += sweeps
+            if mx.enabled:
+                mx.inc("engine.select.rank_sweeps", sweeps)
             with mx.span("engine.select.unpack"):
                 fresh = {}
                 for i, c in zip(lanes, ready):

@@ -1,11 +1,25 @@
 """NSGA-II (Deb et al. 2002), fully vectorized in JAX.
 
-The whole genetic loop is a single `lax.scan` over generations; every
-generation evaluates the entire population with two matmuls (see
-objectives.py), computes dominance (P x P boolean algebra), peels fronts
-with a `while_loop`, and applies tournament selection / uniform crossover
-/ bit-flip mutation / exact-k repair as vectorized bit ops. On TPU this
-turns the paper's per-client CPU hot loop into an MXU-shaped batch job.
+The whole genetic loop is a single `lax.scan` over generations. The
+initial population is evaluated, ranked and crowded once; each generation
+then breeds P children from the carried ranks and crowding (tournament
+selection / uniform crossover / bit-flip mutation / exact-k repair as
+vectorized bit ops), evaluates only the children with two matmuls (see
+objectives.py), and ranks the 2P pool of survivors and children once:
+dominance is P x P boolean algebra, and the fronts are peeled with a
+`while_loop` only until P members have one (the front that crosses P is
+peeled whole). Crowding is assigned within the pool's fronts, and the
+first P by rank, then crowding, survive with their objectives, pool ranks
+and pool crowding, which the next tournament reads. A survivor's pool rank
+is its rank among the survivors, because every front before the last one
+admitted survives whole. That holds only if the survival order is exact:
+both orders are one `lax.sort` over two keys (rank, value for crowding;
+rank, then crowding descending, for survival). The float32 composite keys
+they replace, `rank * 1e9 + value` and `rank * 1e9 - crowding`, lost the
+value at every rank >= 1 (float32's spacing near 1e9 is 64) and put a
+front's boundary members (crowding 1e9 an objective) ahead of the front
+before it. On TPU this turns the paper's per-client CPU hot loop into an
+MXU-shaped batch job.
 
 Two entry points, one program (DESIGN.md §3):
 
@@ -23,7 +37,8 @@ share one GA random sequence through `NSGAConfig.seed`.
 
 The batched run carries device scopes in its op names (DESIGN.md §11):
 `ga`, with `ga/init`, `ga/eval` (every objective evaluation), `ga/rank`
-(front peeling and crowding), `ga/breed` and `ga/survive`.
+(front peeling and crowding), `ga/breed` and `ga/survive`, and returns
+each client's peeling sweeps (`rank_sweeps`).
 """
 from __future__ import annotations
 
@@ -51,14 +66,20 @@ def dominance(objs):
     return ge & gt
 
 
-def nondominated_rank(objs):
-    """(P,) rank per individual (0 = Pareto front) by iterative peeling."""
+def _peel(objs, stop_at=None):
+    """Front peeling: (ranks (P,), sweeps). Each sweep gives the members
+    that no remaining member dominates the next rank. With `stop_at`,
+    peeling ends once at least `stop_at` members have a rank (the front
+    that crosses it is peeled whole); the rest keep the sentinel P."""
     P = objs.shape[0]
     dom = dominance(objs)  # (P, P)
 
     def cond(state):
         ranks, remaining, r = state
-        return jnp.any(remaining) & (r < P)
+        go = jnp.any(remaining) & (r < P)
+        if stop_at is not None:
+            go &= P - jnp.sum(remaining) < stop_at
+        return go
 
     def body(state):
         ranks, remaining, r = state
@@ -68,29 +89,61 @@ def nondominated_rank(objs):
         return ranks, remaining & ~front, r + 1
 
     ranks0 = jnp.full((P,), P, jnp.int32)
-    ranks, _, _ = jax.lax.while_loop(
+    ranks, _, sweeps = jax.lax.while_loop(
         cond, body, (ranks0, jnp.ones((P,), bool), jnp.int32(0)))
-    return ranks
+    return ranks, sweeps
+
+
+def nondominated_rank(objs, stop_at=None):
+    """(P,) rank per individual (0 = Pareto front) by iterative peeling.
+    With `stop_at`, only the fronts holding the first `stop_at` members
+    are peeled; members left unranked keep the sentinel P."""
+    return _peel(objs, stop_at)[0]
+
+
+def _unpermute(sorted_vals, order):
+    """`out[order[j]] = sorted_vals[j]`, as a one-hot select and sum: exact
+    (one nonzero term per output) and free of the scatter a TPU runs one
+    index at a time."""
+    idx = jnp.arange(order.shape[0], dtype=order.dtype)
+    return jnp.sum(jnp.where(order[None, :] == idx[:, None],
+                             sorted_vals[None, :], 0.0), axis=1)
 
 
 def crowding_distance(objs, ranks):
-    """(P,) crowding distance computed within each rank front."""
+    """(P,) crowding distance computed within each rank front. Per
+    objective the members are put in the exact (rank, value) order, ties
+    by index, with one `lax.sort` over the two keys; an interior member
+    adds the gap between its neighbours over the objective's range, a
+    front's first and last get BIG."""
     P, n_obj = objs.shape
+    idx = jnp.arange(P, dtype=jnp.int32)
     dist = jnp.zeros((P,), jnp.float32)
     for m in range(n_obj):
         v = objs[:, m]
-        key = ranks.astype(jnp.float32) * BIG + v
-        order = jnp.argsort(key)  # sorted by (rank, value)
-        v_sorted = v[order]
-        r_sorted = ranks[order]
-        prev_ok = jnp.concatenate([jnp.array([False]), r_sorted[1:] == r_sorted[:-1]])
-        next_ok = jnp.concatenate([r_sorted[1:] == r_sorted[:-1], jnp.array([False])])
+        r_sorted, v_sorted, order = jax.lax.sort((ranks, v, idx), num_keys=2)
+        same = r_sorted[1:] == r_sorted[:-1]
+        prev_ok = jnp.concatenate([jnp.array([False]), same])
+        next_ok = jnp.concatenate([same, jnp.array([False])])
         prev_v = jnp.concatenate([v_sorted[:1], v_sorted[:-1]])
         next_v = jnp.concatenate([v_sorted[1:], v_sorted[-1:]])
         span = jnp.maximum(jnp.max(v) - jnp.min(v), 1e-12)
         contrib = jnp.where(prev_ok & next_ok, (next_v - prev_v) / span, BIG)
-        dist = dist.at[order].add(contrib)
+        dist = dist + _unpermute(contrib, order)
     return dist
+
+
+def survival_order(ranks, crowd, objs):
+    """The pool in survival order: rank ascending, then crowding
+    descending, ties by index, by one exact `lax.sort` over the two keys,
+    so every member of a lower front comes before any member of a higher
+    one. Returns (order, ranks, crowd, objs), each in that order: the sort
+    carries the survivors' state along, where a TPU would gather it one
+    element at a time."""
+    idx = jnp.arange(ranks.shape[0], dtype=jnp.int32)
+    ranks, neg, order, *cols = jax.lax.sort(
+        (ranks, -crowd, idx) + tuple(objs.T), num_keys=2)
+    return order, ranks, -neg, jnp.stack(cols, axis=1)
 
 
 def _tournament(key, ranks, crowd, n):
@@ -164,11 +217,12 @@ def _breed(pop, ranks, crowd, key_g, cfg: NSGAConfig, valid_mask=None):
     return child
 
 
-def _rank_crowd(objs):
-    """(N, P, n_obj) -> per-client (ranks, crowding), device scope `rank`."""
+def _rank_crowd(objs, stop_at=None):
+    """(N, P, n_obj) -> per-client (ranks, crowding, peeling sweeps),
+    device scope `rank`."""
     with jax.named_scope("rank"):
-        ranks = jax.vmap(nondominated_rank)(objs)
-        return ranks, jax.vmap(crowding_distance)(objs, ranks)
+        ranks, sweeps = jax.vmap(lambda o: _peel(o, stop_at))(objs)
+        return ranks, jax.vmap(crowding_distance)(objs, ranks), sweeps
 
 
 def run_nsga2(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
@@ -200,12 +254,20 @@ def run_nsga2_batched(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
     `keys`: (N, 2) uint32 — one independent PRNG stream per client.
     `valid_mask`: optional (N, M) 0/1 per-client model-slot mask.
 
-    The genetic operators are vmapped over the client axis; the two
-    objective evaluations per generation see the full (N, P|2P, M)
-    population, which is what lets a batched Pallas kernel score every
-    client in a single launch (kernels/ensemble_fitness). Each
-    generation: evaluate, rank, breed, then elitist (mu + lambda)
-    survival over the combined 2P pool.
+    The genetic operators are vmapped over the client axis; each
+    objective evaluation sees the full (N, P, M) population, which is
+    what lets a batched Pallas kernel score every client in a single
+    launch (kernels/ensemble_fitness). The initial population is
+    evaluated, ranked and crowded once; each generation then breeds from
+    the carried ranks and crowding, evaluates only the P children, ranks
+    the 2P pool until P members have a front, and keeps the first P by
+    rank, then crowding (elitist (mu + lambda) survival). The survivors'
+    objectives, pool ranks and pool crowding are carried on: a survivor's
+    rank in the pool is its rank among the survivors, since every front
+    before the last one admitted survives whole.
+
+    Returns dict(pop, objs, ranks, rank_sweeps): the final population,
+    its objectives and ranks, and each client's peeling sweeps.
     """
     P, M, k = cfg.pop_size, n_models, cfg.k
     with jax.named_scope("ga"):
@@ -229,9 +291,33 @@ def run_nsga2_batched(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
         def breed_one(pop_c, ranks_c, crowd_c, key_c, vm_c):
             return _breed(pop_c, ranks_c, crowd_c, key_c, cfg, vm_c)
 
-        def gen(pop, keys_g):  # pop: (N, P, M); keys_g: (N, 2)
-            objs = evaluate(pop)                              # (N, P, n_obj)
-            ranks, crowd = _rank_crowd(objs)
+        def start(args):
+            """Step 0: the initial population, ranked whole."""
+            _, _, _, _, sweeps, child, cobjs = args
+            ranks, crowd, n = _rank_crowd(cobjs)
+            return child, cobjs, ranks, crowd, sweeps + n
+
+        def survive(args):
+            """Steps 1..G: the first P of the 2P pool."""
+            pop, objs, _, _, sweeps, child, cobjs = args
+            with jax.named_scope("survive"):
+                allp = jnp.concatenate([pop, child], axis=1)  # (N, 2P, M)
+                aobjs = jnp.concatenate([objs, cobjs], axis=1)
+            aranks, acrowd, n = _rank_crowd(aobjs, stop_at=P)
+            with jax.named_scope("survive"):     # rank asc, crowding desc
+                order, aranks, acrowd, aobjs = jax.vmap(survival_order)(
+                    aranks, acrowd, aobjs)
+                return (jnp.take_along_axis(allp, order[:, :P, None], axis=1),
+                        aobjs[:, :P], aranks[:, :P], acrowd[:, :P], sweeps + n)
+
+        def gen(state, xs):  # pop: (N, P, M); keys_g: (N, 2)
+            # Step 0 evaluates the initial population and steps 1..G the
+            # children, all at this one call: a chromosome met twice must
+            # score the same, and XLA may round an evaluation fused
+            # elsewhere otherwise (a duplicate would then dominate its
+            # twin).
+            g, keys_g = xs
+            pop, objs, ranks, crowd, sweeps = state
             with jax.named_scope("breed"):
                 if valid_mask is None:
                     child = jax.vmap(
@@ -240,20 +326,22 @@ def run_nsga2_batched(eval_fn: Callable, n_models: int, cfg: NSGAConfig,
                 else:
                     child = jax.vmap(breed_one)(pop, ranks, crowd, keys_g,
                                                 valid_mask)
-            with jax.named_scope("survive"):
-                allp = jnp.concatenate([pop, child], axis=1)  # (N, 2P, M)
-            aranks, acrowd = _rank_crowd(evaluate(allp))
-            with jax.named_scope("survive"):     # rank asc, crowding desc
-                order = jax.vmap(jnp.argsort)(
-                    aranks.astype(jnp.float32) * BIG - acrowd)
-                pop = jnp.take_along_axis(allp, order[:, :P, None], axis=1)
-            return pop, None
+                child = jnp.where(g == 0, pop, child)
+            cobjs = evaluate(child)                           # (N, P, n_obj)
+            return jax.lax.cond(g == 0, start, survive,
+                                state + (child, cobjs)), None
 
-        pop, _ = jax.lax.scan(gen, pop, gkeys)
-        objs = evaluate(pop)
-        with jax.named_scope("rank"):
-            ranks = jax.vmap(nondominated_rank)(objs)
-        return {"pop": pop, "objs": objs, "ranks": ranks}
+        N = pop.shape[0]
+        objs = jax.eval_shape(eval_fn, pop)
+        state = (pop, jnp.zeros(objs.shape, objs.dtype),
+                 jnp.zeros((N, P), jnp.int32), jnp.zeros((N, P), jnp.float32),
+                 jnp.zeros((N,), jnp.int32))
+        step_keys = jnp.concatenate(   # step 0 breeds nothing it keeps
+            [jnp.zeros((1,) + gkeys.shape[1:], gkeys.dtype), gkeys])
+        (pop, objs, ranks, _, sweeps), _ = jax.lax.scan(
+            gen, state, (jnp.arange(cfg.generations + 1), step_keys))
+        return {"pop": pop, "objs": objs, "ranks": ranks,
+                "rank_sweeps": sweeps}
 
 
 def client_keys(seed: int, client_ids) -> jnp.ndarray:

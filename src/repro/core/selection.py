@@ -21,8 +21,10 @@ from .objectives import (ensemble_accuracy, member_accuracy,
                          population_objectives, similarity_matrix)
 
 
-def _pick_winner(pop, objs, ranks, probs_val, labels_val, acc):
-    """Shared post-GA step: best overall-accuracy member of the front."""
+def _pick_winner(pop, objs, ranks, rank_sweeps, probs_val, labels_val, acc):
+    """Shared post-GA step: best overall-accuracy member of the front.
+    `rank_sweeps` (the GA's peeling sweeps) rides along for the engine's
+    counter."""
     pareto = ranks == 0
     overall = ensemble_accuracy(pop, probs_val, labels_val)
     score = jnp.where(pareto, overall, -1.0)
@@ -34,6 +36,7 @@ def _pick_winner(pop, objs, ranks, probs_val, labels_val, acc):
         "pareto_mask": pareto,
         "pop": pop,
         "objs": objs,
+        "rank_sweeps": rank_sweeps,
     }
 
 
@@ -50,7 +53,8 @@ def select_ensemble(probs_val, labels_val, nsga: NSGAConfig,
     Returns dict with:
       chromosome (M,) 0/1 — the selected ensemble,
       pop/objs/pareto_mask — the final population and its front (Fig. 3),
-      val_accuracy — overall validation accuracy of the winner.
+      val_accuracy — overall validation accuracy of the winner,
+      rank_sweeps — the GA's front-peeling sweeps.
     """
     if key is None:
         key = jax.random.PRNGKey(nsga.seed)
@@ -94,7 +98,8 @@ def _ga_stage(acc, S, probs_val, labels_val, nsga: NSGAConfig,
     out = run_nsga2_batched(eval_fn, M, nsga, keys, valid_mask=model_mask)
     with jax.named_scope("vote"):     # the device scope of the winner pick
         return jax.vmap(_pick_winner)(out["pop"], out["objs"], out["ranks"],
-                                      probs_val, labels_val, acc)
+                                      out["rank_sweeps"], probs_val,
+                                      labels_val, acc)
 
 
 @partial(jax.jit, static_argnames=("nsga", "use_kernel"))

@@ -268,3 +268,28 @@ def test_async_final_state_matches_sync_selection():
     for c in range(N_CLIENTS):
         np.testing.assert_array_equal(engine_async.chromosome(c),
                                       engine_sync.chromosome(c))
+
+
+def test_select_counts_rank_sweeps_and_keeps_them_out_of_results():
+    """Each select adds its NSGA-II's peeling sweeps (the most over its
+    real lanes) to `n_rank_sweeps` and `engine.select.rank_sweeps`; with
+    the pool peeled only until P members have a front, a select at
+    P = G = 100 needs about one sweep a generation, under 3·G. No
+    per-client result carries the count."""
+    from repro.obs.metrics import Metrics
+    nsga = NSGAConfig(pop_size=100, generations=100, k=5, seed=7)
+    capacity, labels, mats = _make_world(seed=2, n_clients=8)
+    engine = SelectionEngine(_full_stores(capacity, labels, mats,
+                                          n_clients=8), nsga,
+                             metrics=Metrics())
+    seen = []
+    for clients in ([0, 1, 2], [5]):
+        fresh = engine.select(clients)
+        counted = engine.metrics.frame().scalars["engine.select.rank_sweeps"]
+        seen.append((engine.n_rank_sweeps, counted))
+        assert all("rank_sweeps" not in r for r in fresh.values())
+        assert all("rank_sweeps" not in r for r in engine.results.values())
+    (first, c1), (both, c2) = seen
+    assert c1 == first and c2 == both
+    assert nsga.generations < first < 3 * nsga.generations
+    assert first < both

@@ -9,7 +9,7 @@ from hypothesis import strategies as st
 
 from repro.core.nsga2 import (NSGAConfig, crowding_distance, dominance,
                               keep_top_k, nondominated_rank, repair_k,
-                              run_nsga2)
+                              run_nsga2, run_nsga2_batched, survival_order)
 from repro.core.objectives import (ensemble_accuracy, member_accuracy,
                                    population_objectives, similarity_matrix)
 from repro.core.selection import select_ensemble
@@ -54,6 +54,171 @@ def test_keep_top_k_gives_k_ones_when_scores_tie(row, k, want):
     repair's float32 noise ties about once in a million rows)."""
     got = np.asarray(keep_top_k(jnp.asarray([row], jnp.float32), k))
     np.testing.assert_array_equal(got, [want])
+
+
+def _pool(draw_seed, P, n_obj, grid):
+    """(P, n_obj) float32 objectives; with `grid`, drawn from a few values
+    so that duplicates and tied objectives are common."""
+    rng = np.random.default_rng(draw_seed)
+    if grid:
+        return rng.integers(0, grid, size=(P, n_obj)).astype(np.float32) / grid
+    return rng.normal(size=(P, n_obj)).astype(np.float32)
+
+
+pools = st.tuples(st.integers(0, 10_000), st.sampled_from([2, 3, 7, 16, 40]),
+                  st.integers(2, 3), st.sampled_from([0, 3, 6]))
+rank = jax.jit(nondominated_rank, static_argnames="stop_at")
+
+
+@settings(max_examples=40, deadline=None)
+@given(pools, st.floats(0.0, 1.0))
+def test_truncated_rank_agrees_with_full_rank(pool, frac):
+    """Peeling stops once `stop_at` members have a front: every ranked
+    member has its full rank, at least `stop_at` are ranked, the fronts
+    ranked are whole, and the rest keep the sentinel P."""
+    seed, P, n_obj, grid = pool
+    objs = jnp.asarray(_pool(seed, P, n_obj, grid))
+    s = 1 + int(frac * (P - 1))
+    full = np.asarray(rank(objs))
+    trunc = np.asarray(rank(objs, stop_at=s))
+    ranked = trunc < P
+    np.testing.assert_array_equal(trunc[ranked], full[ranked])
+    assert (trunc[~ranked] == P).all()
+    assert ranked.sum() >= s
+    if (~ranked).any():
+        assert full[~ranked].min() > full[ranked].max()
+
+
+@jax.jit
+def _survival(objs, ranks):
+    return survival_order(ranks, crowding_distance(objs, ranks), objs)[0]
+
+
+def _survivors(objs, ranks, n):
+    return np.asarray(_survival(objs, ranks))[:n]
+
+
+@settings(max_examples=40, deadline=None)
+@given(pools)
+def test_truncated_rank_keeps_the_same_survivors(pool):
+    """Survival of P from a 2P pool picks the same members, in the same
+    order, whether the pool is ranked whole or only until P have a front."""
+    seed, P, n_obj, grid = pool
+    objs = jnp.asarray(_pool(seed, 2 * P, n_obj, grid))
+    full = _survivors(objs, rank(objs), P)
+    trunc = _survivors(objs, rank(objs, stop_at=P), P)
+    np.testing.assert_array_equal(trunc, full)
+
+
+@settings(max_examples=40, deadline=None)
+@given(pools)
+def test_survivors_pool_ranks_are_their_own_ranks(pool):
+    """The carry's lemma: every front before the last one admitted
+    survives whole, so a survivor's rank in the pool is its rank among
+    the survivors."""
+    seed, P, n_obj, grid = pool
+    objs = jnp.asarray(_pool(seed, 2 * P, n_obj, grid))
+    ranks = rank(objs, stop_at=P)
+    keep = _survivors(objs, ranks, P)
+    np.testing.assert_array_equal(np.asarray(ranks)[keep],
+                                  np.asarray(rank(objs[keep])))
+
+
+def test_survival_admits_front0_whole_before_front1():
+    """A front-1 boundary member has crowding 2·BIG; the old key
+    `rank * BIG - crowd` put it ahead of front 0's interior members, so a
+    dominated member survived while nondominated ones were dropped."""
+    objs = jnp.asarray([[0, 4], [1, 3], [2, 2], [3, 1], [4, 0],   # front 0
+                        [0, 3], [3, 0], [1, 1]], jnp.float32)     # front 1
+    ranks = nondominated_rank(objs)
+    np.testing.assert_array_equal(np.asarray(ranks), [0] * 5 + [1] * 3)
+    crowd = crowding_distance(objs, ranks)
+    old = np.argsort(np.asarray(ranks.astype(jnp.float32) * 1e9 - crowd))
+    assert np.asarray(ranks)[old[:4]].max() == 1     # the fault, as it was
+    order, r_sorted, c_sorted, o_sorted = survival_order(ranks, crowd, objs)
+    order = np.asarray(order)
+    assert (np.diff(np.asarray(ranks)[order]) >= 0).all()
+    np.testing.assert_array_equal(np.sort(order[:5]), [0, 1, 2, 3, 4])
+    # the state sorted along is the members' own
+    np.testing.assert_array_equal(np.asarray(r_sorted), np.asarray(ranks)[order])
+    np.testing.assert_array_equal(np.asarray(c_sorted), np.asarray(crowd)[order])
+    np.testing.assert_array_equal(np.asarray(o_sorted), np.asarray(objs)[order])
+
+
+def _crowding_ref(objs, ranks):
+    """Crowding in float32 numpy, each front put in (value, index) order."""
+    objs, ranks = np.asarray(objs), np.asarray(ranks)
+    dist = np.zeros(len(ranks), np.float32)
+    for m in range(objs.shape[1]):
+        v = objs[:, m]
+        span = np.maximum(v.max() - v.min(), np.float32(1e-12))
+        contrib = np.zeros(len(ranks), np.float32)
+        for r in np.unique(ranks):
+            members = np.flatnonzero(ranks == r)
+            front = members[np.lexsort((members, v[members]))]
+            contrib[front[0]] = contrib[front[-1]] = np.float32(1e9)
+            for a, i, b in zip(front, front[1:], front[2:]):
+                contrib[i] = (v[b] - v[a]) / span
+        dist = dist + contrib
+    return dist
+
+
+@settings(max_examples=40, deadline=None)
+@given(pools, st.integers(1, 6))
+def test_crowding_orders_every_front_by_value(pool, n_fronts):
+    """Inside fronts of rank >= 1 too, neighbours are neighbours in value:
+    the old key `rank * BIG + value` lost the value at every rank >= 1
+    (float32's spacing near 1e9 is 64), leaving index order."""
+    seed, P, n_obj, grid = pool
+    objs = _pool(seed, P, n_obj, grid)
+    ranks = np.random.default_rng(seed + 1).integers(0, n_fronts, P)
+    np.testing.assert_array_equal(
+        np.asarray(crowding_distance(jnp.asarray(objs),
+                                     jnp.asarray(ranks, jnp.int32))),
+        _crowding_ref(objs, ranks))
+
+
+def test_crowding_in_a_higher_front_follows_value_not_index():
+    objs = jnp.asarray([[0.9, 0.1], [0.1, 0.9], [0.5, 0.5], [0.3, 0.7]])
+    for r in (0, 1, 5):
+        d = np.asarray(crowding_distance(objs, jnp.full((4,), r, jnp.int32)))
+        assert d[0] > 1e8 and d[1] > 1e8
+        np.testing.assert_allclose(d[2:], [2 * 0.6 / 0.8, 2 * 0.4 / 0.8],
+                                   rtol=1e-6)
+
+
+@pytest.mark.parametrize("tied", [False, True])
+@pytest.mark.parametrize("masked", [False, True])
+def test_ga_returns_objectives_and_ranks_of_its_population(tied, masked):
+    """The carried objectives and pool ranks of the final population are
+    what a fresh evaluation and a full rank of it give, bit for bit
+    (`tied`: accuracies on a coarse grid, so many ensembles tie)."""
+    N, M, cfg = 3, 16, NSGAConfig(pop_size=24, generations=12, k=4)
+    rng = np.random.default_rng(5)
+    acc = rng.uniform(0.3, 0.9, (N, M)).astype(np.float32)
+    S = rng.uniform(0.0, 1.0, (N, M, M)).astype(np.float32)
+    if tied:
+        acc = np.round(acc * 4) / 4
+    S = jnp.asarray((S + np.swapaxes(S, 1, 2)) / 2)
+    acc = jnp.asarray(acc)
+    mask = (jnp.asarray(rng.random((N, M)) < 0.7, jnp.float32)
+            .at[:, :cfg.k].set(1.0) if masked else None)
+
+    def eval_fn(pop):
+        st_, dv = jax.vmap(population_objectives)(pop, acc, S)
+        return jnp.stack([st_, dv], axis=2)
+
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(N))
+    out = jax.jit(lambda k, vm: run_nsga2_batched(
+        eval_fn, M, cfg, k, valid_mask=vm))(keys, mask)
+    np.testing.assert_array_equal(np.asarray(out["objs"]),
+                                  np.asarray(jax.jit(eval_fn)(out["pop"])))
+    np.testing.assert_array_equal(
+        np.asarray(out["ranks"]),
+        np.asarray(jax.vmap(nondominated_rank)(out["objs"])))
+    sweeps = np.asarray(out["rank_sweeps"])
+    assert sweeps.shape == (N,)
+    assert (sweeps >= cfg.generations + 1).all()
 
 
 def test_crowding_boundary_is_infinite():
