@@ -84,11 +84,11 @@ def bench_table4_cost():
     from repro.configs.paper_cnn import config as paper_config
     from repro.models.cnn import CNNConfig
     pc = paper_config(True)
-    fp = pc["fedpae"]
-    ccfg = CNNConfig(n_classes=10, width=fp.width)
-    f_avg = np.mean([family_forward_flops(f, ccfg) for f in fp.families])
-    N, M, T, D, V = 20, 5, fp.max_epochs, 2100, 450
-    P, G = fp.nsga.pop_size, fp.nsga.generations
+    tr, sel = pc["train"], pc["selection"]
+    ccfg = CNNConfig(n_classes=10, width=tr.width)
+    f_avg = np.mean([family_forward_flops(f, ccfg) for f in tr.families])
+    N, M, T, D, V = 20, 5, tr.max_epochs, 2100, 450
+    P, G = sel.pop_size, sel.generations
     f_fit = 2 * (N * M) ** 2 + 2 * N * M
     fedpae = N * (M * 3 * f_avg * T * D + P * G * f_fit + 10 * V * f_avg)
     rounds = N * 500 * 1 * 10 * 3 * f_avg

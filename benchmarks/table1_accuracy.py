@@ -13,8 +13,9 @@ import numpy as np
 
 from benchmarks.common import make_clients
 from repro.configs.paper_cnn import config as paper_config
-from repro.core.fedpae import run_fedpae, run_local_ensemble
+from repro.configs.paper_cnn import spec as paper_spec
 from repro.fl.baselines import BASELINES, FLConfig
+from repro.sim import Experiment
 
 METHODS = ["fedavg", "fedprox", "feddistill", "lg_fedavg", "fedkd", "fedgh",
            "fml", "local", "fedpae"]
@@ -34,13 +35,13 @@ def run_grid(full=False, alphas=None, rounds=None, out="results/table1.json",
                                            pc["n_samples"], n_classes, seed=seed)
                 fl = FLConfig(rounds=rounds or (400 if full else 60),
                               local_steps=2,
-                              families=pc["fedpae"].families,
-                              width=pc["fedpae"].width, seed=seed)
-                local_acc, models, ccfg = run_local_ensemble(
-                    datasets, n_classes, pc["fedpae"])
+                              families=pc["train"].families,
+                              width=pc["train"].width, seed=seed)
+                exp = Experiment(paper_spec(pc, n_classes, seed=seed),
+                                 datasets=datasets)
+                local_acc = exp.local_ensemble()
                 results[key]["local"] = local_acc.tolist()
-                res = run_fedpae(datasets, n_classes, pc["fedpae"],
-                                 models=models, ccfg=ccfg)
+                res = exp.run()
                 results[key]["fedpae"] = res.test_acc.tolist()
                 results[key]["fedpae_local_frac"] = res.local_frac.tolist()
                 for m in METHODS:

@@ -26,29 +26,8 @@ import os
 import numpy as np
 
 from repro.configs.paper_cnn import config as paper_config
-from repro.sim import (DataSpec, Experiment, ExperimentSpec, ScheduleSpec,
-                       SelectionSpec, TrainSpec)
-
-
-def spec_for(n_classes: int, alpha: float, seed: int,
-             pc: dict) -> ExperimentSpec:
-    """One Table-II grid cell as a declarative spec (sync protocol —
-    the paper's Table I/II setting)."""
-    fp = pc["fedpae"]
-    nsga = fp.nsga
-    return ExperimentSpec(
-        data=DataSpec(kind="synthetic_images", n_clients=pc["n_clients"],
-                      n_classes=n_classes, n_samples=pc["n_samples"],
-                      alpha=alpha),
-        train=TrainSpec(families=tuple(fp.families), lr=fp.lr,
-                        batch=fp.batch, max_epochs=fp.max_epochs,
-                        patience=fp.patience, width=fp.width),
-        selection=SelectionSpec(pop_size=nsga.pop_size,
-                                generations=nsga.generations, k=nsga.k,
-                                p_mut=nsga.p_mut, p_cross=nsga.p_cross,
-                                ensemble_k=fp.ensemble_k),
-        schedule=ScheduleSpec(mode="sync"),
-        seed=seed)
+from repro.configs.paper_cnn import spec as paper_spec
+from repro.sim import Experiment
 
 
 def run_grid(full=False, alphas=(0.1,), seeds=(0,)):
@@ -61,8 +40,9 @@ def run_grid(full=False, alphas=(0.1,), seeds=(0,)):
         for alpha in alphas:
             for seed in seeds:
                 key = f"{dname}|{alpha}|{seed}"
-                exp = Experiment.from_spec(
-                    spec_for(n_classes, alpha, seed, pc))
+                exp = Experiment.from_spec(paper_spec(
+                    pc, n_classes, seed=seed, kind="synthetic_images",
+                    n_samples=pc["n_samples"], alpha=alpha))
                 local_acc = exp.local_ensemble()
                 res = exp.run()
                 results[key] = {

@@ -10,8 +10,9 @@ import numpy as np
 
 from benchmarks.common import make_clients
 from repro.configs.paper_cnn import config as paper_config
-from repro.core.fedpae import run_fedpae, run_local_ensemble
+from repro.configs.paper_cnn import spec as paper_spec
 from repro.fl.baselines import BASELINES, FLConfig
+from repro.sim import Experiment
 
 
 def main(full=False, scale=2, out="results/table3.json"):
@@ -20,11 +21,13 @@ def main(full=False, scale=2, out="results/table3.json"):
     n_classes = list(pc["datasets"].values())[0]
     datasets, _ = make_clients(n_clients, 0.1, pc["n_samples"], n_classes, seed=0)
     fl = FLConfig(rounds=400 if full else 60, local_steps=2,
-                  families=pc["fedpae"].families, width=pc["fedpae"].width)
+                  families=pc["train"].families, width=pc["train"].width)
     results = {}
-    local_acc, models, ccfg = run_local_ensemble(datasets, n_classes, pc["fedpae"])
+    exp = Experiment(paper_spec(pc, n_classes, n_clients=n_clients),
+                     datasets=datasets)
+    local_acc = exp.local_ensemble()
     results["local"] = local_acc.tolist()
-    res = run_fedpae(datasets, n_classes, pc["fedpae"], models=models, ccfg=ccfg)
+    res = exp.run()
     results["fedpae"] = res.test_acc.tolist()
     for m in ("fedavg", "feddistill", "lg_fedavg", "fedkd", "fml", "fedgh"):
         results[m] = BASELINES[m](datasets, n_classes, fl).tolist()

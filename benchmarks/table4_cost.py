@@ -14,9 +14,10 @@ import numpy as np
 from benchmarks.common import make_clients
 from repro.obs.metrics import Stopwatch
 from repro.configs.paper_cnn import config as paper_config
-from repro.core.fedpae import run_fedpae, run_local_ensemble
+from repro.configs.paper_cnn import spec as paper_spec
 from repro.fl.baselines import BASELINES, FLConfig
 from repro.models.cnn import CNNConfig, init_model
+from repro.sim import Experiment
 
 
 def conv_flops(shape_in, w_shape, stride=1):
@@ -41,35 +42,36 @@ def family_forward_flops(family: str, ccfg: CNNConfig, img=10):
 def main(full=False):
     pc = paper_config(full)
     n_classes = list(pc["datasets"].values())[0]
-    fp = pc["fedpae"]
-    ccfg = CNNConfig(n_classes=n_classes, width=fp.width)
+    tr, sel = pc["train"], pc["selection"]
+    ccfg = CNNConfig(n_classes=n_classes, width=tr.width)
     datasets, _ = make_clients(pc["n_clients"], 0.1, pc["n_samples"], n_classes)
     N = len(datasets)
     D = int(np.mean([len(d.x_tr) for d in datasets]))
     V = int(np.mean([len(d.x_va) for d in datasets]))
 
-    f_fwd = {f: family_forward_flops(f, ccfg) for f in fp.families}
+    f_fwd = {f: family_forward_flops(f, ccfg) for f in tr.families}
     f_avg = float(np.mean(list(f_fwd.values())))
-    T = fp.max_epochs  # epochs over D samples
-    P, G = fp.nsga.pop_size, fp.nsga.generations
-    M = len(fp.families)
+    T = tr.max_epochs  # epochs over D samples
+    P, G = sel.pop_size, sel.generations
+    M = len(tr.families)
     # NSGA fitness evaluation cost: P x (matvec M + quadform M^2) per gen
     f_fit = 2 * (N * M) ** 2 + 2 * N * M
     fedpae_flops = N * (M * 3 * f_avg * T * D + P * G * f_fit + 10 * V * f_avg)
 
     fl = FLConfig(rounds=400 if full else 60, local_steps=2,
-                  families=fp.families, width=fp.width)
+                  families=tr.families, width=tr.width)
     round_flops = N * fl.rounds * fl.local_steps * fl.batch * 3 * f_avg
 
     rows = [("fedpae_analytic", fedpae_flops), ("round_based_analytic", round_flops)]
 
     # measured wall-clock on the reduced grid
+    exp = Experiment(paper_spec(pc, n_classes), datasets=datasets)
     sw = Stopwatch()
     sw.start()
-    local_acc, models, ccfg2 = run_local_ensemble(datasets, n_classes, fp)
+    exp.local_ensemble()
     t_train = sw.stop()
     sw.start()
-    run_fedpae(datasets, n_classes, fp, models=models, ccfg=ccfg2)
+    exp.run()
     t_select = sw.stop()
     sw.start()
     BASELINES["fedavg"](datasets, n_classes, fl)

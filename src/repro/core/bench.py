@@ -324,7 +324,3 @@ def stack_stores(stores, clients=None, v_to: Optional[int] = None):
         masks[i] = s.mask.astype(np.float32)
     return preds, labels, masks
 
-
-# Backwards-compatible name: the callable-based ModelBench was replaced by
-# the tensor-resident PredictionStore in the batched-engine refactor.
-ModelBench = PredictionStore

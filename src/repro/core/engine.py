@@ -1,6 +1,6 @@
 """The unified selection engine: ONE batched selection path shared by the
-synchronous driver (`run_fedpae`) and the discrete-event asynchronous
-simulator (`run_fedpae_async`).
+synchronous round and the discrete-event asynchronous simulator, both
+driven by the spec layer's `Experiment`.
 
 The engine owns every client's `PredictionStore` and, by default, a
 device-resident mirror of the whole fleet (`DeviceStoreBatch`,

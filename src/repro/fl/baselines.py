@@ -11,7 +11,7 @@ is the only fully decentralized/asynchronous method in the table).
                per-class feature prototypes (het. bodies)
   fml        — mutual distillation with a shared small aux model (cnn4)
   fedkd      — like FML with scheduled distillation weight + aux averaging
-  local      — per-client local ensemble (in core/fedpae.py)
+  local      — per-client local ensemble (`Experiment.local_ensemble`)
 """
 from __future__ import annotations
 

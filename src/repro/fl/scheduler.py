@@ -115,7 +115,6 @@ def _select_tick(t: float, debounce: float) -> int:
 
 
 def simulate_async(cfg: AsyncConfig, neighbors, train_cost: Callable,
-                   on_select: Optional[Callable] = None,
                    on_add: Optional[Callable] = None,
                    on_select_batch: Optional[Callable] = None,
                    transport=None, gossip=None, churn=None,
@@ -125,10 +124,9 @@ def simulate_async(cfg: AsyncConfig, neighbors, train_cost: Callable,
     on_add(client, model_key, t) — a model (own or peer) entered the
       client's bench; the engine uses this to incrementally materialize
       the prediction store.
-    on_select(client, bench_ids, t) -> val_acc (or None to skip recording).
     on_select_batch(clients, {client: bench_ids}, t) -> {client: val_acc}
-      — preferred: all clients whose debounced select fires at time t are
-      handed over in ONE call for batched (vmapped) re-selection.
+      — all clients whose debounced select fires at time t are handed
+      over in ONE call for batched (vmapped) re-selection.
     transport/gossip/churn — optional p2p layers (see module docstring);
       with none given the legacy single-hop, lossless exchange runs, but
       with per-edge deterministic latency streams.
@@ -181,7 +179,7 @@ def simulate_async(cfg: AsyncConfig, neighbors, train_cost: Callable,
     n_lost_offline = 0  # sends/recvs swallowed because an endpoint was away
     trace = AsyncTrace(events=[], bench_sizes={c: [] for c in range(cfg.n_clients)},
                        selections={c: [] for c in range(cfg.n_clients)})
-    want_select = on_select is not None or on_select_batch is not None
+    want_select = on_select_batch is not None
 
     def push(t, kind, c, payload, src=-1):
         nonlocal seq
@@ -545,15 +543,6 @@ def simulate_async(cfg: AsyncConfig, neighbors, train_cost: Callable,
                     record_selection(b, t, accs.get(b))
                 if serving is not None:
                     serving.note_selected(ready, t)
-            elif on_select is not None:
-                if tc is not None:
-                    tc.slice(c, "select x1", t, t, cat="select",
-                             args={"clients": 1})
-                with sw_select(t=t):
-                    acc = on_select(c, sorted(bench[c]), t)
-                record_selection(c, t, acc)
-                if serving is not None:
-                    serving.note_selected([c], t)
 
     if transport is not None or gossip is not None or churn is not None \
             or faults is not None:

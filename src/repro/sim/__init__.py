@@ -13,7 +13,6 @@ through `repro.sim.registry`; importing this package registers the stock
 set (`repro.sim.build`).
 """
 from repro.sim import build as _build  # noqa: F401  (registers components)
-from repro.sim.compat import fedpae_config, spec_from_fedpae
 from repro.sim.experiment import Experiment, RunResult
 from repro.sim.registry import known, register, resolve
 from repro.sim.spec import (ComponentSpec, DataSpec, ExperimentSpec,
@@ -23,6 +22,6 @@ from repro.sim.spec import (ComponentSpec, DataSpec, ExperimentSpec,
 __all__ = [
     "ComponentSpec", "DataSpec", "Experiment", "ExperimentSpec",
     "FaultSpec", "NetworkSpec", "ObsSpec", "RunResult", "ScheduleSpec",
-    "SelectionSpec", "ServeSpec", "TrainSpec", "fedpae_config", "known",
-    "register", "resolve", "spec_from_fedpae",
+    "SelectionSpec", "ServeSpec", "TrainSpec", "known", "register",
+    "resolve",
 ]

@@ -1,4 +1,5 @@
-"""Spec -> concrete objects: stock component builders and world builders.
+"""Spec -> concrete objects: stock component builders, world builders,
+and the image worlds' local training and store exchange.
 
 Importing this module registers the stock components:
 
@@ -20,7 +21,12 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.core.bench import (BenchEntry, PredictionStore,
+                              StreamingPredictionStore)
+from repro.fl.client import (ClientData, predict_probs,
+                             predict_probs_batched, train_local_model)
 from repro.fl.topology import make_topology
+from repro.models.cnn import CNNConfig, n_params
 from repro.obs import probes as _obs_probes
 from repro.p2p.churn import ChurnSchedule
 from repro.p2p.params import check_params
@@ -30,7 +36,8 @@ from repro.p2p.transport import (GossipTransport, checkpoint_bytes,
                                  prediction_matrix_bytes)
 from repro.sim.registry import build as build_component
 from repro.sim.registry import register
-from repro.sim.spec import ComponentSpec, DataSpec, ExperimentSpec
+from repro.sim.spec import (ComponentSpec, DataSpec, ExperimentSpec,
+                            TrainSpec)
 
 # ---- stock train-cost models ------------------------------------------
 
@@ -279,8 +286,8 @@ def build_network(spec: ExperimentSpec, n_clients: int,
     `neighbors`, `transport`, `gossip`, `churn`, `repair`, `train_cost`
     (absent layers are None); the scheduler consumes them directly.
 
-    `injected` maps slot names to caller-built collaborators (the
-    compatibility shims' path). An injected instance takes its slot AND
+    `injected` maps slot names to caller-built collaborators
+    (`Experiment`'s keyword overrides). An injected instance takes its slot AND
     participates in the build context, so spec-built dependents wire
     against the object that will actually run — a spec-declared repair
     component around an injected gossip must reconcile THAT gossip's
@@ -324,7 +331,6 @@ def build_client_datasets(data: DataSpec, default_seed: int):
     skew, 70/15/15 per-client splits)."""
     from repro.data import (dirichlet_partition, make_synthetic_images,
                             split_train_val_test)
-    from repro.fl.client import ClientData
     seed = data.seed if data.seed is not None else default_seed
     split_seed = data.split_seed if data.split_seed is not None \
         else seed + 1
@@ -383,9 +389,8 @@ def build_prediction_world(data: DataSpec, default_seed: int
 def build_world_stores(data: DataSpec, labels: dict,
                        store_capacity: Optional[int]):
     """Empty (streaming) stores for a prediction world: bounded iff the
-    capacity is below the global model count (mirrors
-    core.fedpae._empty_stores for the trainingless world)."""
-    from repro.core.bench import PredictionStore, StreamingPredictionStore
+    capacity is below the global model count (mirrors `empty_stores` for
+    the trainingless world)."""
     n, mpc, C = data.n_clients, data.models_per_client, data.n_classes
     full = n * mpc
     cap = full if store_capacity is None else store_capacity
@@ -395,3 +400,74 @@ def build_world_stores(data: DataSpec, labels: dict,
                 for c in range(n)]
     return [StreamingPredictionStore(c, cap, x_val[c], labels[c], C)
             for c in range(n)]
+
+
+# ---- image worlds: local training and the exchange ---------------------
+
+
+def train_all_clients(datasets, train: TrainSpec, n_classes: int,
+                      seed: int):
+    """Local training: every client trains each family of `train`.
+    Returns ({(client, family): (params, val_acc)}, ccfg)."""
+    models = {}
+    ccfg = CNNConfig(n_classes=n_classes, width=train.width,
+                     in_channels=datasets[0].x_tr.shape[-1])
+    for c, data in enumerate(datasets):
+        for fi, fam in enumerate(train.families):
+            params, va, _ = train_local_model(
+                fam, ccfg, seed * 10007 + c * 101 + fi, data, lr=train.lr,
+                batch=train.batch, max_epochs=train.max_epochs,
+                patience=train.patience)
+            models[(c, fam)] = (params, va)
+    return models, ccfg
+
+
+def make_entry(owner: int, fam: str, fam_idx: int, models, ccfg,
+               n_families: int) -> BenchEntry:
+    params, _ = models[(owner, fam)]
+    # carrying (params, ccfg) lets the store serve same-family members
+    # through one vmapped multi-model forward (bench.predictions)
+    return BenchEntry(
+        model_id=owner * n_families + fam_idx, owner=owner, family=fam,
+        predict=(lambda x, f=fam, p=params: predict_probs(f, ccfg, p, x)),
+        n_params=n_params(params), params=params, ccfg=ccfg)
+
+
+def empty_stores(datasets, n_families: int, n_classes: int,
+                 store_capacity: Optional[int]):
+    """Slot-aligned stores: slot owner*F+fam_idx on every client, padded
+    to one common validation width so all stacks share a jit signature.
+    With `store_capacity` set (and smaller than the global model count)
+    each client gets a bounded streaming store with contribution-aware
+    eviction instead (DESIGN.md §6)."""
+    full_capacity = len(datasets) * n_families
+    v_max = max(len(d.y_va) for d in datasets)
+    if store_capacity is not None and store_capacity < full_capacity:
+        return [StreamingPredictionStore(c, store_capacity, d.x_va,
+                                         d.y_va, n_classes, v_pad=v_max)
+                for c, d in enumerate(datasets)]
+    return [PredictionStore(c, full_capacity, d.x_va, d.y_va, n_classes,
+                            v_pad=v_max)
+            for c, d in enumerate(datasets)]
+
+
+def build_stores(datasets, models, ccfg, families: tuple,
+                 store_capacity: Optional[int], topology: str, seed: int):
+    """The synchronous exchange over the topology (full graph = paper
+    setup). Each reachable family is materialized with ONE batched
+    multi-model forward per (family, client) — the exchange-layer hot
+    path."""
+    n, F = len(datasets), len(families)
+    neighbors = make_topology(topology, n, seed=seed)
+    stores = empty_stores(datasets, F, ccfg.n_classes, store_capacity)
+    for c in range(n):
+        reachable = sorted(set([c] + list(neighbors[c]))) \
+            if topology != "full" else list(range(n))
+        for fi, fam in enumerate(families):
+            params_seq = [models[(o, fam)][0] for o in reachable]
+            fam_preds = predict_probs_batched(fam, ccfg, params_seq,
+                                              datasets[c].x_va)
+            for o, pv in zip(reachable, fam_preds):
+                stores[c].add(make_entry(o, fam, fi, models, ccfg, F),
+                              preds=pv)
+    return stores

@@ -6,13 +6,13 @@ synchronous or asynchronous driver, and returns a structured `RunResult`
 (test accuracy, val-acc curves, dissemination coverage, net counters,
 spec echo) that sweep harnesses consume directly (DESIGN.md §9).
 
-The legacy drivers ride on top: `run_fedpae` / `run_fedpae_async`
-(repro.core.fedpae) lift their kwargs into a spec and inject their
-caller-constructed collaborators through `Experiment(...)`'s keyword
-overrides — injected objects take the place of registry-built ones, and
-everything else is built from the spec. Both paths execute the same
-driver code, so a shim run and a pure-spec run of the same scenario
-produce bit-identical traces (proven in tests/test_spec.py).
+Callers that already hold collaborators (datasets, trained models,
+transport/gossip/churn/repair objects, a train-cost model) inject them
+through `Experiment(...)`'s keyword overrides: injected objects take the
+place of registry-built ones, and everything else is built from the
+spec. Both paths execute the same driver code, so an injected run and a
+pure-spec run of the same scenario produce bit-identical traces (proven
+in tests/test_spec.py).
 
 `build()` without `run()` materializes datasets / models / stores /
 engine for analysis scripts that drive selection themselves (e.g.
@@ -28,14 +28,15 @@ import numpy as np
 
 from repro.core.bench import BenchEntry
 from repro.core.engine import SelectionEngine
-from repro.fl.client import accuracy
+from repro.fl.client import accuracy, predict_probs
 from repro.fl.scheduler import AsyncConfig, AsyncTrace, simulate_async
 from repro.obs.metrics import json_ready
 from repro.obs.probes import attach_metrics, finalize_run, make_obs
 from repro.sim.build import (_seeded, build_client_datasets, build_faults,
                              build_network, build_prediction_world,
-                             build_serving, build_world_stores)
-from repro.sim.compat import fedpae_config
+                             build_serving, build_stores,
+                             build_world_stores, empty_stores, make_entry,
+                             train_all_clients)
 from repro.sim.spec import ExperimentSpec
 
 _IMAGE_KINDS = ("synthetic_images", "external")
@@ -104,9 +105,9 @@ class RunResult:
 class Experiment:
     """Builds and runs the scenario an `ExperimentSpec` describes.
 
-    Keyword overrides inject pre-built collaborators (the compatibility
-    shims' path): anything injected is used as-is, anything absent is
-    built from the spec through the component registry.
+    Keyword overrides inject pre-built collaborators: anything injected
+    is used as-is, anything absent is built from the spec through the
+    component registry.
     """
 
     def __init__(self, spec: ExperimentSpec, *, datasets=None,
@@ -177,22 +178,18 @@ class Experiment:
             else self.world
 
     def _ensure_models(self) -> None:
-        """Local training (images worlds only). Reuses the core helper so
-        seeds — and therefore traces — match the legacy drivers."""
-        from repro.core.fedpae import train_all_clients
+        """Local training (images worlds only)."""
         if self.spec.data.kind not in _IMAGE_KINDS or \
                 self.models is not None:
             return
         self._ensure_world()
-        cfg = fedpae_config(self.spec)
-        self.models, self.ccfg = train_all_clients(self.datasets, cfg,
-                                                   self.n_classes)
+        self.models, self.ccfg = train_all_clients(
+            self.datasets, self.spec.train, self.n_classes, self.spec.seed)
 
     def build(self) -> "Experiment":
         """Materialize everything the run needs: world, trained models,
         stores (filled for sync, empty for async), engine, and — async —
         the registry-built p2p stack. Idempotent."""
-        from repro.core.fedpae import _empty_stores, build_stores
         if self._built:
             return self
         spec = self.spec
@@ -254,11 +251,13 @@ class Experiment:
                     "run as if the declared network had been simulated)")
         if data.kind in _IMAGE_KINDS:
             self._ensure_models()
-            cfg = fedpae_config(spec)
-            self.stores = (build_stores(self.datasets, self.models,
-                                        self.ccfg, cfg) if sync
-                           else _empty_stores(self.datasets, cfg,
-                                              self.n_classes))
+            families = spec.train.families
+            self.stores = (
+                build_stores(self.datasets, self.models, self.ccfg,
+                             families, sel.store_capacity,
+                             spec.network.topology, spec.seed) if sync
+                else empty_stores(self.datasets, len(families),
+                                  self.n_classes, sel.store_capacity))
         elif data.kind == "prediction_world":
             labels, _ = self.world
             self.stores = build_world_stores(data, labels,
@@ -270,7 +269,6 @@ class Experiment:
                 seed=sel.seed if sel.seed is not None else spec.seed,
                 ensemble_k=(sel.ensemble_k if sel.ensemble_k is not None
                             else sel.k),
-                device_resident=sel.device_resident,
                 metrics=self.obs.metrics if self.obs is not None
                 else None, shards=sel.shards)
         if not sync:
@@ -380,8 +378,7 @@ class Experiment:
 
     def _run_sync(self) -> RunResult:
         """The paper's synchronous protocol: stores complete, ONE batched
-        selection over every client, then masked lazy serving (the body
-        of the legacy `run_fedpae`)."""
+        selection over every client, then masked lazy serving."""
         engine, stores = self.engine, self.stores
         if engine is None:
             raise ValueError('schedule.mode="sync" requires '
@@ -439,20 +436,19 @@ class Experiment:
         chaos = faults is not None or adm is not None
         base_entry = None
         if data.kind in _IMAGE_KINDS:
-            from repro.core.fedpae import _make_entry
             families = spec.train.families
             models, ccfg, F = self.models, self.ccfg, len(families)
 
             if not chaos:
                 def on_add(c, model_key, t):
                     owner, m = model_key
-                    stores[c].add(_make_entry(owner, families[m], m,
-                                              models, ccfg, F), t=t)
+                    stores[c].add(make_entry(owner, families[m], m,
+                                             models, ccfg, F), t=t)
             else:
                 def base_entry(c, model_key):
                     owner, m = model_key
-                    entry = _make_entry(owner, families[m], m, models,
-                                        ccfg, F)
+                    entry = make_entry(owner, families[m], m, models,
+                                       ccfg, F)
                     return entry, entry.predict(stores[c].x_val)
         elif data.kind == "prediction_world":
             _, mats = self.world
@@ -565,9 +561,12 @@ class Experiment:
         """The paper's 'local' baseline on this experiment's world and
         models: each client mean-prob votes over only its own locally
         trained models. Trains (or reuses) the same models as `run()`."""
-        from repro.core.fedpae import run_local_ensemble
         self._ensure_models()
-        accs, self.models, self.ccfg = run_local_ensemble(
-            self.datasets, self.n_classes, fedpae_config(self.spec),
-            models=self.models, ccfg=self.ccfg)
-        return accs
+        accs = []
+        for c, data in enumerate(self.datasets):
+            probs = np.stack([predict_probs(f, self.ccfg,
+                                            self.models[(c, f)][0],
+                                            data.x_te)
+                              for f in self.spec.train.families])
+            accs.append(accuracy(probs.mean(0), data.y_te))
+        return np.array(accs)

@@ -9,10 +9,10 @@ scenario. Five sections mirror the five things a run needs:
                    prediction-matrix world with no CNN training
                    ("prediction_world"), a pure dissemination run with no
                    stores at all ("none"), or caller-provided datasets
-                   ("external", the compatibility-shim path).
+                   ("external", injected through `Experiment(datasets=)`).
   TrainSpec      — local training: model families, lr, epochs, width.
-  SelectionSpec  — NSGA-II shape, ensemble size, kernel/device-resident
-                   switches, bounded store capacity, shard count.
+  SelectionSpec  — NSGA-II shape, ensemble size, kernel switch, bounded
+                   store capacity, shard count.
   NetworkSpec    — topology plus four TAGGED component slots (transport,
                    gossip, churn, repair), each a `ComponentSpec` resolved
                    by name through `repro.sim.registry` so new transports
@@ -167,7 +167,6 @@ class SelectionSpec:
     p_cross: float = 0.9
     ensemble_k: Optional[int] = None      # None -> k
     use_kernel: bool = False
-    device_resident: bool = True
     store_capacity: Optional[int] = None  # bounded streaming stores (§6)
     shards: int = 1                       # devices the client axis of the
                                           # device batch is split over (§15)

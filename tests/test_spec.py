@@ -1,18 +1,15 @@
 """Spec-layer tests (DESIGN.md §9): serialization round-trips, strict
-unknown-name/field errors, registry resolution, and the golden-trace
-guarantee — the legacy `run_fedpae_async` shim and the pure spec path
-produce bit-identical traces for the same scenario and seed."""
+unknown-name/field errors, registry resolution, the paper presets, and
+the golden-trace guarantee — a run with caller-injected collaborators
+and the pure spec path produce bit-identical traces for the same
+scenario and seed."""
+import dataclasses
 import json
-import warnings
 
 import numpy as np
 import pytest
 
-from repro.core.fedpae import (FedPAEConfig, build_benches, build_stores,
-                               run_fedpae, run_fedpae_async,
-                               train_all_clients)
-from repro.core.nsga2 import NSGAConfig
-from repro.fl.scheduler import AsyncConfig
+from repro.configs import paper_cnn
 from repro.fl.topology import make_topology
 from repro.p2p.params import check_params
 from repro.p2p import (AntiEntropyRepair, ChurnConfig, ChurnSchedule,
@@ -21,7 +18,7 @@ from repro.p2p import (AntiEntropyRepair, ChurnConfig, ChurnSchedule,
                        prediction_matrix_bytes)
 from repro.sim import (ComponentSpec, DataSpec, Experiment, ExperimentSpec,
                        NetworkSpec, ScheduleSpec, SelectionSpec, TrainSpec,
-                       register, resolve, spec_from_fedpae)
+                       register, resolve)
 from repro.sim.build import build_client_datasets
 from repro.sim.run import apply_override
 
@@ -282,22 +279,27 @@ def test_run_is_single_shot():
         exp.run()
 
 
-# ---- golden trace: shim == spec path ----------------------------------
+# ---- golden trace: injected collaborators == spec-built ones ----------
+
+def _external(spec, topology="full"):
+    """The same scenario with caller-injected datasets: the data section
+    names only the grid, the network only the topology."""
+    return dataclasses.replace(
+        spec, data=DataSpec(kind="external", n_clients=spec.data.n_clients,
+                            n_classes=spec.data.n_classes),
+        network=NetworkSpec(topology=topology))
+
 
 def test_golden_trace_shim_vs_spec_lossy_churn():
-    """The acceptance claim: the legacy `run_fedpae_async(...)` shim
-    (hand-constructed transport/gossip/churn/repair collaborators) and
-    the pure spec path produce BIT-IDENTICAL traces for the same
-    8-client lossy+churn scenario and seed."""
+    """The acceptance claim: the same 8-client lossy+churn scenario run
+    from the spec alone and with caller-injected datasets and
+    hand-constructed transport/gossip/churn/repair collaborators
+    produces BIT-IDENTICAL traces for the same seed."""
     n, n_classes = 8, 4
     spec = lossy_churn_spec(n, n_classes)
     r_spec = Experiment.from_spec(spec).run()
 
-    # legacy path: the same scenario wired by hand
-    cfg = FedPAEConfig(
-        families=("cnn4",), ensemble_k=3,
-        nsga=NSGAConfig(pop_size=8, generations=2, k=3, seed=0),
-        topology="ring", width=8, max_epochs=2, patience=2, seed=0)
+    # the same scenario wired by hand
     datasets = build_client_datasets(spec.data, spec.seed)
     nb = make_topology("ring", n, seed=0)
     churn = ChurnSchedule(ChurnConfig(availability_beta=0.2,
@@ -312,20 +314,20 @@ def test_golden_trace_shim_vs_spec_lossy_churn():
     repair = AntiEntropyRepair(
         RepairConfig(max_rounds=30, max_attempts=6, seed=0), gossip,
         churn=churn)
-    r_legacy = run_fedpae_async(datasets, n_classes, cfg,
-                                transport=transport, gossip=gossip,
-                                churn=churn, repair=repair)
+    r_injected = Experiment(_external(spec, "ring"), datasets=datasets,
+                            transport=transport, gossip=gossip,
+                            churn=churn, repair=repair).run()
 
-    assert r_spec.trace.events == r_legacy.trace.events
-    assert r_spec.trace.net == r_legacy.trace.net
-    assert r_spec.trace.select_batches == r_legacy.trace.select_batches
-    assert np.array_equal(r_spec.test_acc, r_legacy.test_acc)
+    assert r_spec.trace.events == r_injected.trace.events
+    assert r_spec.trace.net == r_injected.trace.net
+    assert r_spec.trace.select_batches == r_injected.trace.select_batches
+    assert np.array_equal(r_spec.test_acc, r_injected.test_acc)
 
 
 def test_golden_sync_shim_vs_spec():
-    """The sync twin of the golden-trace claim: `run_fedpae` (shim) and
-    the pure spec path agree bit-for-bit on accuracies, local fractions,
-    and chromosomes for the same scenario and seed."""
+    """The sync twin of the golden-trace claim: a run with caller-injected
+    datasets and the pure spec path agree bit-for-bit on accuracies,
+    local fractions, and chromosomes for the same scenario and seed."""
     n, n_classes = 3, 4
     spec = ExperimentSpec(
         data=DataSpec(kind="synthetic_images", n_clients=n,
@@ -338,72 +340,46 @@ def test_golden_sync_shim_vs_spec():
         schedule=ScheduleSpec(mode="sync"), seed=0)
     r_spec = Experiment.from_spec(spec).run()
 
-    cfg = FedPAEConfig(families=("cnn4",), ensemble_k=2,
-                       nsga=NSGAConfig(pop_size=8, generations=2, k=2),
-                       width=8, max_epochs=2, patience=2, seed=0)
     datasets = build_client_datasets(spec.data, spec.seed)
-    r_legacy = run_fedpae(datasets, n_classes, cfg)
+    r_injected = Experiment(_external(spec), datasets=datasets).run()
 
-    assert np.array_equal(r_spec.test_acc, r_legacy.test_acc)
-    assert np.array_equal(r_spec.local_frac, r_legacy.local_frac)
+    assert np.array_equal(r_spec.test_acc, r_injected.test_acc)
+    assert np.array_equal(r_spec.local_frac, r_injected.local_frac)
     assert all(np.array_equal(a, b) for a, b in
-               zip(r_spec.chromosomes, r_legacy.chromosomes))
+               zip(r_spec.chromosomes, r_injected.chromosomes))
 
-
-# ---- legacy-shim satellites -------------------------------------------
 
 def test_async_grid_mismatch_raises_valueerror_with_shapes():
-    spec = lossy_churn_spec(4, 4)
-    datasets = build_client_datasets(spec.data, 0)
-    cfg = FedPAEConfig(families=("cnn4",), nsga=NSGAConfig(
-        pop_size=8, generations=2, k=1), width=8, max_epochs=1,
-        patience=1)
-    bad = AsyncConfig(n_clients=7, models_per_client=3)
+    """Injected datasets must match the spec's client grid; the error
+    names both counts."""
+    datasets = build_client_datasets(lossy_churn_spec(4, 4).data, 0)
     with pytest.raises(ValueError) as ei:
-        run_fedpae_async(datasets, 4, cfg, acfg=bad)
+        Experiment(lossy_churn_spec(7, 4), datasets=datasets)
     msg = str(ei.value)
-    assert "n_clients=7" in msg and "models_per_client=3" in msg
-    assert "n_clients=4" in msg and "models_per_client=1" in msg
+    assert "(4 clients)" in msg and "n_clients=7" in msg
 
 
-def test_build_benches_emits_deprecation_warning():
-    spec = lossy_churn_spec(2, 4)
-    datasets = build_client_datasets(spec.data, 0)[:2]
-    cfg = FedPAEConfig(families=("cnn4",), nsga=NSGAConfig(
-        pop_size=8, generations=2, k=1), width=8, max_epochs=1,
-        patience=1)
-    models, ccfg = train_all_clients(datasets, cfg, 4)
-    with pytest.warns(DeprecationWarning, match="build_stores"):
-        stores = build_benches(datasets, models, ccfg, cfg)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # the real name must stay silent
-        expected = build_stores(datasets, models, ccfg, cfg)
-    assert len(stores) == len(expected)
+# ---- the paper presets (repro.configs.paper_cnn) -----------------------
 
-
-def test_fedpae_config_default_nsga_not_shared():
-    a, b = FedPAEConfig(), FedPAEConfig()
-    assert a.nsga == b.nsga
-    assert a.nsga is not b.nsga  # default_factory: no aliased default
-
-
-def test_spec_from_fedpae_preserves_knobs():
-    cfg = FedPAEConfig(families=("cnn4", "vgg"), ensemble_k=2,
-                       nsga=NSGAConfig(pop_size=12, generations=3, k=2),
-                       topology="ring", store_capacity=6,
-                       device_resident=False, seed=9)
-    acfg = AsyncConfig(n_clients=5, models_per_client=2,
-                       speed_lognorm_sigma=0.9, select_debounce=0.25,
-                       seed=9)
-    spec = spec_from_fedpae(cfg, n_clients=5, n_classes=8, mode="async",
-                            acfg=acfg)
-    assert spec.data.kind == "external"
-    assert spec.train.families == ("cnn4", "vgg")
-    assert spec.selection.store_capacity == 6
-    assert spec.selection.device_resident is False
-    assert spec.network.topology == "ring"
-    assert spec.schedule.speed_lognorm_sigma == 0.9
-    assert spec.schedule.select_debounce == 0.25
-    assert spec.seed == 9
-    # and it still serializes
-    assert ExperimentSpec.from_dict(spec.to_dict()) == spec
+@pytest.mark.parametrize("preset", ["full", "reduced", "smoke"])
+def test_paper_cnn_preset_builds_spec(preset):
+    pc = {"full": lambda: paper_cnn.config(full=True),
+          "reduced": paper_cnn.config, "smoke": paper_cnn.smoke}[preset]()
+    n_classes = max(pc["datasets"].values())
+    for spec in (paper_cnn.spec(pc, n_classes),
+                 paper_cnn.spec(pc, n_classes, seed=3,
+                                kind="synthetic_images",
+                                n_samples=pc["n_samples"],
+                                alpha=pc["alphas"][0])):
+        assert ExperimentSpec.from_json(spec.to_json()) == spec
+        assert spec.data.n_clients == pc["n_clients"]
+        assert spec.schedule.mode == "sync"
+        exp = Experiment.from_spec(spec)
+        assert exp.models_per_client == len(pc["train"].families)
+    if preset == "full":  # the paper's own sizes
+        sel = spec.selection
+        assert pc["n_clients"] == 20
+        assert spec.train.families == ("cnn4", "vgg", "resnet",
+                                       "densenet", "inception")
+        assert (sel.pop_size, sel.generations) == (100, 100)
+        assert sel.k == sel.ensemble_k == 5

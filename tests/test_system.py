@@ -9,10 +9,10 @@ heterogeneity rises.
 import numpy as np
 import pytest
 
-from repro.core.fedpae import FedPAEConfig, run_fedpae, run_local_ensemble
-from repro.core.nsga2 import NSGAConfig
 from repro.data import dirichlet_partition, make_synthetic_images, split_train_val_test
 from repro.fl.client import ClientData
+from repro.sim import (DataSpec, Experiment, ExperimentSpec, ScheduleSpec,
+                       SelectionSpec, TrainSpec)
 
 
 def _make_clients(n_clients=4, alpha=0.1, n=1800, n_classes=8, seed=0):
@@ -29,12 +29,18 @@ def _make_clients(n_clients=4, alpha=0.1, n=1800, n_classes=8, seed=0):
 @pytest.fixture(scope="module")
 def fedpae_run():
     datasets, n_classes = _make_clients()
-    cfg = FedPAEConfig(families=("cnn4", "vgg", "resnet"), ensemble_k=3,
-                       nsga=NSGAConfig(pop_size=32, generations=20, k=3),
-                       max_epochs=10, patience=4, width=12)
-    local_acc, models, ccfg = run_local_ensemble(datasets, n_classes, cfg)
-    res = run_fedpae(datasets, n_classes, cfg, models=models, ccfg=ccfg)
-    return datasets, cfg, local_acc, res
+    spec = ExperimentSpec(
+        data=DataSpec(kind="external", n_clients=len(datasets),
+                      n_classes=n_classes),
+        train=TrainSpec(families=("cnn4", "vgg", "resnet"), max_epochs=10,
+                        patience=4, width=12),
+        selection=SelectionSpec(pop_size=32, generations=20, k=3,
+                                ensemble_k=3),
+        schedule=ScheduleSpec(mode="sync"), seed=0)
+    exp = Experiment(spec, datasets=datasets)
+    local_acc = exp.local_ensemble()
+    res = exp.run()
+    return datasets, spec, local_acc, res
 
 
 def test_fedpae_beats_or_matches_local(fedpae_run):
@@ -49,9 +55,9 @@ def test_fedpae_reasonable_absolute_accuracy(fedpae_run):
 
 
 def test_ensembles_have_exact_k(fedpae_run):
-    _, cfg, _, res = fedpae_run
+    _, spec, _, res = fedpae_run
     for chrom in res.chromosomes:
-        assert chrom.sum() == cfg.ensemble_k
+        assert chrom.sum() == spec.selection.ensemble_k
 
 
 def test_local_fraction_bounded(fedpae_run):
@@ -62,6 +68,6 @@ def test_local_fraction_bounded(fedpae_run):
 def test_negative_transfer_bounded_per_client(fedpae_run):
     """Paper Table II: per-client FedPAE accuracy never falls far below
     that client's own local ensemble."""
-    datasets, cfg, local_acc, res = fedpae_run
+    datasets, spec, local_acc, res = fedpae_run
     rel = (res.test_acc - local_acc) / np.maximum(local_acc, 1e-9)
     assert rel.min() > -0.12, f"negative transfer too large: {rel}"
